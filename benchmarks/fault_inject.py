@@ -325,6 +325,9 @@ def phase_sweep() -> dict:
     from repro.corpus.datasets import synthetic_corpus
     from repro.corpus.sweep import RECORDS_FILENAME, load_records, run_sweep
 
+    from repro.runtime import refuse_if_chip_held
+
+    refuse_if_chip_held("the sweep phase")
     entries = synthetic_corpus("smoke")[:4]
     with tempfile.TemporaryDirectory() as tmp:
         journal = Path(tmp) / RECORDS_FILENAME
@@ -446,9 +449,12 @@ def phase_dist() -> dict:
     """Per-shard crash/hang/wrong-result under a real 4-fake-device mesh
     (subprocess): the compile degrades to the baseline on the crashed
     shard, the pooled hang is killed by the cooperative deadline, and the
-    sharded plan stays oracle-exact with failure_counts aggregated."""
+    sharded plan stays oracle-exact with failure_counts aggregated. The
+    child's mesh is four host devices, so it runs on the CPU and never
+    needs the chip this process may hold."""
+    env = dict(_child_env(), JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, "-c", DIST_SCRIPT],
-                          capture_output=True, text=True, env=_child_env(),
+                          capture_output=True, text=True, env=env,
                           timeout=WALL_GUARD_S)
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
@@ -578,14 +584,15 @@ def main(argv=None) -> int:
         deadline_s, n_requests = 60.0, 256
     target = repro.Target(batch_size=8)
 
+    # first: its child needs the device, which this process must not hold
+    sweep_stats = phase_sweep()
+    print(f"sweep:  {sweep_stats}", flush=True)
     store_stats = phase_store(m, target)
     print(f"store:  {store_stats}", flush=True)
     search_stats = phase_search(m, target, deadline_s)
     print(f"search: {search_stats}", flush=True)
     serve_stats = phase_serve(m, target, n_requests)
     print(f"serve:  {serve_stats}", flush=True)
-    sweep_stats = phase_sweep()
-    print(f"sweep:  {sweep_stats}", flush=True)
     dist_stats = phase_dist()
     print(f"dist:   {dist_stats}", flush=True)
     dyn_stats = phase_dyn(n_requests)

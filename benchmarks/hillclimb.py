@@ -34,10 +34,9 @@ from pathlib import Path  # noqa: E402
 
 
 def _record(tag, compiled, cfg, out_dir):
-    from repro.launch.compat import normalize_cost_analysis
     from repro.launch.dryrun import collective_stats
     from repro.models import n_blocks
-    ca = normalize_cost_analysis(compiled.cost_analysis())
+    ca = dict(compiled.cost_analysis())
     ma = compiled.memory_analysis()
     rec = {
         "tag": tag,

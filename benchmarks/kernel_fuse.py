@@ -9,11 +9,12 @@ benchmark measures the end-to-end SpMV win, combine included, plus the
 mixed-precision storage axis (bf16 vals + int16 cols, fp32 accumulate).
 
 Per family (the 4 regularity axes of the Figure 9 suite) it times, on the
-Pallas backend (interpret=True — the CPU stand-in for Mosaic):
+Pallas backend (Mosaic on a TPU, the Pallas interpreter elsewhere):
 
 * ``base``  — ``fuse_combine=False, tiles_per_step=1``: the historical
   kernel + jnp-scatter path;
-* ``fused`` — in-kernel combine + megatile grid steps;
+* ``fused`` — no scatter pass (seg: resident y block; ELL: the row slab
+  lands in y by one slice add) + megatile grid steps;
 * ``bf16``  — the fused path with bf16/int16 storage (traffic halved).
 
 Parity is checked against the dense float64 oracle before any timing
@@ -70,11 +71,11 @@ def bench_one(name: str, m, tiles: int, repeats: int) -> dict:
     oracle = m.spmv_dense_oracle(np.asarray(x))
     scale = float(np.abs(oracle).max()) + 1e-30
 
-    base = build_program(meta, backend="pallas", interpret=True,
+    base = build_program(meta, backend="pallas",
                          fuse_combine=False, tiles_per_step=1)
-    fused = build_program(meta, backend="pallas", interpret=True,
+    fused = build_program(meta, backend="pallas",
                           fuse_combine=True, tiles_per_step=tiles)
-    bf16 = build_program(meta, backend="pallas", interpret=True,
+    bf16 = build_program(meta, backend="pallas",
                          fuse_combine=True, tiles_per_step=tiles,
                          storage_dtype="bfloat16")
 

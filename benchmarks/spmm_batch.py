@@ -4,8 +4,8 @@ The serving hot path used to batch decode by vmapping a 1-RHS program over
 the B activation columns — re-streaming the format arrays B times. The
 fused SpMM path hands the program one (n_cols, B) tile; this benchmark
 measures the win at the decode batch size on the Pallas backend
-(interpret=True — the CPU stand-in for Mosaic; relative timings reflect
-the B-fold reduction in grid steps / format streams).
+(Mosaic on a TPU, the Pallas interpreter elsewhere; relative timings
+reflect the B-fold reduction in grid steps / format streams).
 
 Four matrix families (the regularity axes of the paper's Figure 9 suite):
 ``banded`` (stencil-regular), ``uniform`` (random-regular), ``powerlaw``
@@ -56,7 +56,7 @@ def spmm_families(smoke: bool) -> dict:
 def bench_one(name: str, m, batch: int, repeats: int) -> dict:
     graph = default_shard_graph(m)
     meta = run_graph(m, graph)
-    prog = build_program(meta, backend="pallas", interpret=True)
+    prog = build_program(meta, backend="pallas")
     rng = np.random.default_rng(0)
     X = jnp.asarray(rng.standard_normal((m.n_cols, batch)).astype(np.float32))
     Xrows = jnp.asarray(np.asarray(X).T)          # legacy (B, n_cols) layout

@@ -5,8 +5,8 @@ machine-designed format + kernel out" (paper §III). This module is that
 contract as a single surface:
 
 * :class:`Target` — where the program runs: backend ("jax" | "pallas"),
-  interpret mode, an optional device mesh (sharded execution), partition
-  mode/balance, decode batch size, dtype.
+  an optional device mesh (sharded execution), partition mode/balance,
+  decode batch size, dtype.
 * :func:`compile` — matrix + Target (+ search budget) in, :class:`SpmvPlan`
   out. ``budget`` is a ``SearchConfig`` (or seconds); ``graph=`` skips the
   search and designs with a fixed Operator Graph.
@@ -47,6 +47,7 @@ from repro.core.matrices import SparseMatrix
 from repro.core.search import (ProgramCache, SearchConfig, SearchResult,
                                _graph_from_jsonable, _graph_to_jsonable,
                                run_search)
+from repro.runtime import resolve_interpret
 
 __all__ = ["Target", "SpmvPlan", "ShardedSpmvPlan", "PlanStore", "PlanWatch",
            "PlanIntegrityError", "compile", "load_plan"]
@@ -137,8 +138,9 @@ class Target:
     """Where a compiled plan runs.
 
     ``backend="jax"`` is the pure-jnp program (CPU oracle / timing);
-    ``"pallas"`` the TPU kernels (``interpret=True`` is the CPU stand-in
-    for Mosaic). A non-None ``mesh`` compiles a sharded plan over
+    ``"pallas"`` the TPU kernels: Mosaic-lowered on a TPU, the Pallas
+    interpreter elsewhere (``repro.runtime.resolve_interpret``). A
+    non-None ``mesh`` compiles a sharded plan over
     ``axis_name`` with the given ``partition`` mode ("row" | "col") and
     boundary ``balance`` ("nnz" | "rows"). ``batch_size`` is the number of
     right-hand sides the plan is tuned for (B > 1 makes the search time
@@ -149,7 +151,6 @@ class Target:
     """
 
     backend: str = "jax"
-    interpret: bool = True
     mesh: Optional[object] = None          # jax.sharding.Mesh
     axis_name: str = "data"
     partition: str = "row"
@@ -178,6 +179,12 @@ class Target:
     def key(self) -> str:
         blob = json.dumps(self.spec_dict(), sort_keys=True)
         return hashlib.sha1(blob.encode()).hexdigest()[:8]
+
+    @property
+    def runs_interpreted(self) -> bool:
+        """Whether this Target's kernels run in the Pallas interpreter on
+        this platform (backend="pallas" off a TPU)."""
+        return self.backend == "pallas" and resolve_interpret()
 
 
 def _x_dtype(target: Target):
@@ -226,9 +233,9 @@ def _npz_restore(prefix: str, z) -> dict:
 # ------------------------------ dense plans ---------------------------------
 
 @functools.lru_cache(maxsize=256)
-def _dense_kernel(spec_json: str, backend: str, interpret: bool):
+def _dense_kernel(spec_json: str, backend: str):
     spec = json.loads(spec_json)
-    return jax.jit(build_kernel(spec, backend=backend, interpret=interpret))
+    return jax.jit(build_kernel(spec, backend=backend))
 
 
 @dataclasses.dataclass(eq=False)
@@ -295,8 +302,7 @@ class SpmvPlan:
     def __call__(self, x) -> jax.Array:
         """x: (n_cols,) -> (n_rows,), or (n_cols, B) -> (n_rows, B)."""
         x = jnp.asarray(x, _x_dtype(self.target))
-        fn = _dense_kernel(self.spec_json, self.target.backend,
-                           self.target.interpret)
+        fn = _dense_kernel(self.spec_json, self.target.backend)
         return fn(self.fmt, x)
 
     # -- dynamic sparsity --------------------------------------------------
@@ -323,7 +329,7 @@ class SpmvPlan:
                  f"nnz={spec['nnz']} padded={spec['padded_nnz']} "
                  f"stored={self.stored_bytes}B",
                  f"  target: backend={self.target.backend} "
-                 f"interpret={self.target.interpret} "
+                 f"interpret={self.target.runs_interpreted} "
                  f"batch_size={self.target.batch_size} "
                  f"dtype={self.target.dtype}",
                  f"  graph: {g.label() if g else '(heuristic)'}"]
@@ -339,16 +345,13 @@ class SpmvPlan:
         return "\n".join(lines)
 
     def cost_analysis(self, batch_size: Optional[int] = None) -> dict:
-        """XLA cost analysis of the compiled call, shape-normalized
-        across jax versions (``repro.launch.compat``)."""
-        from repro.launch.compat import normalize_cost_analysis
+        """XLA cost analysis of the compiled call."""
         b = batch_size if batch_size is not None else self.target.batch_size
         shape = (self.n_cols,) if b <= 1 else (self.n_cols, b)
         x = jax.ShapeDtypeStruct(shape, _x_dtype(self.target))
-        fn = _dense_kernel(self.spec_json, self.target.backend,
-                           self.target.interpret)
+        fn = _dense_kernel(self.spec_json, self.target.backend)
         compiled = fn.lower(self.fmt, x).compile()
-        out = normalize_cost_analysis(compiled.cost_analysis())
+        out = dict(compiled.cost_analysis())
         # format capacity headroom (repro.dyn): how much pattern mutation
         # this plan can absorb in place before a re-search is needed
         from repro.dyn.capacity import capacity_report
@@ -376,7 +379,9 @@ class SpmvPlan:
 
 
 def _target_from_dict(d: dict, mesh=None) -> Target:
-    kw = {k: v for k, v in d.items() if k != "mesh"}
+    # plans saved before interpret mode was derived carry an "interpret"
+    # key; it no longer selects anything
+    kw = {k: v for k, v in d.items() if k not in ("mesh", "interpret")}
     return Target(mesh=mesh, **kw)
 
 
@@ -403,10 +408,10 @@ jax.tree_util.register_pytree_node(SpmvPlan, _tree_flatten_plan,
 
 @functools.lru_cache(maxsize=64)
 def _sharded_fn(steps_json: str, mode: str, n_out: int, mesh, axis_name: str,
-                backend: str, interpret: bool):
+                sizes: tuple, n_cols: int, backend: str):
     from repro.dist.spmv import make_stacked_fn
     return make_stacked_fn(json.loads(steps_json), mode, n_out, mesh,
-                           axis_name, backend=backend, interpret=interpret)
+                           axis_name, sizes, n_cols, backend=backend)
 
 
 @dataclasses.dataclass(eq=False)
@@ -470,21 +475,19 @@ class ShardedSpmvPlan:
                    failure_counts=failure_counts,
                    search_result=search_result)
 
-    def _n_out(self) -> int:
-        return self.band_rows if self.mode == "row" else self.n_rows
+    def _fn(self):
+        n_out = self.band_rows if self.mode == "row" else self.n_rows
+        return _sharded_fn(self.steps_json, self.mode, n_out,
+                           self.target.mesh, self.target.axis_name,
+                           tuple(stop - start for start, stop in self.bounds),
+                           self.n_cols, self.target.backend)
 
     def __call__(self, x) -> jax.Array:
         if self.target.mesh is None:
             raise ValueError("sharded plan has no mesh attached; load with "
                              "SpmvPlan.load(path, mesh=...) or rebuild the "
                              "Target with a mesh")
-        from repro.dist.spmv import stacked_call
-        fn = _sharded_fn(self.steps_json, self.mode, self._n_out(),
-                         self.target.mesh, self.target.axis_name,
-                         self.target.backend, self.target.interpret)
-        return stacked_call(fn, self.stacks, x, self.mode, self.n_cols,
-                            [stop - start for start, stop in self.bounds],
-                            dtype=_x_dtype(self.target))
+        return self._fn()(self.stacks, jnp.asarray(x, _x_dtype(self.target)))
 
     def update(self, delta):
         """Sharded plans do not support patch-in-place updates: a delta
@@ -501,7 +504,7 @@ class ShardedSpmvPlan:
                  f"nnz={self.nnz} mode={self.mode} "
                  f"shards={self.n_shards}",
                  f"  target: backend={self.target.backend} "
-                 f"interpret={self.target.interpret} "
+                 f"interpret={self.target.runs_interpreted} "
                  f"axis={self.target.axis_name}",
                  f"  format bytes/device: {self.per_device_format_bytes} "
                  f"(closure baseline {self.replicated_bytes})"]
@@ -513,20 +516,14 @@ class ShardedSpmvPlan:
         return "\n".join(lines)
 
     def cost_analysis(self, batch_size: Optional[int] = None) -> dict:
-        from repro.launch.compat import normalize_cost_analysis
         if self.target.mesh is None:
             raise ValueError("sharded plan has no mesh attached; load with "
                              "SpmvPlan.load(path, mesh=...) first")
         b = batch_size if batch_size is not None else self.target.batch_size
-        n_in = (self.n_cols if self.mode == "row"
-                else -(-self.n_cols // self.n_shards) * self.n_shards)
-        shape = (n_in,) if b <= 1 else (n_in, b)
+        shape = (self.n_cols,) if b <= 1 else (self.n_cols, b)
         x = jax.ShapeDtypeStruct(shape, _x_dtype(self.target))
-        fn = _sharded_fn(self.steps_json, self.mode, self._n_out(),
-                         self.target.mesh, self.target.axis_name,
-                         self.target.backend, self.target.interpret)
-        compiled = fn.lower(self.stacks, x).compile()
-        return normalize_cost_analysis(compiled.cost_analysis())
+        compiled = self._fn().lower(self.stacks, x).compile()
+        return dict(compiled.cost_analysis())
 
     def save(self, path) -> None:
         arrays = _npz_arrays("stack", self.stacks)
@@ -702,13 +699,17 @@ def compile(matrix: SparseMatrix, target: Optional[Target] = None,
       ignore it). With a ``store`` given and no explicit warm start,
       ``store.suggest(matrix)`` (statistics-keyed nearest stored plan)
       seeds the search automatically.
-    * ``deadline_s`` — hard wall-clock budget for the whole compile
-      (dense searched targets). The search's ``max_seconds`` is clamped
-      to it, the seed pass loses its 2x extension, and every candidate
-      runs under a per-candidate deadline derived from the time left —
-      ``compile`` always returns the best plan found so far (at worst
-      the baseline jax-backend source-format program, never an error,
-      as long as the matrix itself is designable).
+    * ``deadline_s`` — wall-clock budget for the whole compile (dense
+      searched targets). The search's ``max_seconds`` is clamped to it,
+      the seed pass loses its 2x extension, every candidate runs under a
+      per-candidate deadline derived from the time left, and a candidate
+      whose estimated compile (its kernel steps x the slowest compile per
+      step so far) does not fit is skipped as a timeout. A compile once
+      started cannot be interrupted, so the first one can still overrun:
+      ``plan.search_result.deadline_overrun_s`` says by how much.
+      ``compile`` returns the best plan found (at worst the baseline
+      jax-backend source-format program, never an error, as long as the
+      matrix itself is designable).
     * ``cache`` — a ``ProgramCache`` memoising raw search results (keyed
       by matrix, budget AND strategy).
     * ``store`` — a :class:`PlanStore`; a prior plan for the same
@@ -741,8 +742,7 @@ def compile(matrix: SparseMatrix, target: Optional[Target] = None,
             meta = run_graph(matrix, graph)
             # Target.dtype overrides the storage dtype for fixed-graph
             # compiles (searched compiles pick it via SET_RESOURCES)
-            prog = build_program(meta, backend=target.backend,
-                                 interpret=target.interpret, jit=False,
+            prog = build_program(meta, backend=target.backend, jit=False,
                                  storage_dtype=(target.dtype
                                                 if target.dtype != "float32"
                                                 else None))
@@ -771,7 +771,6 @@ def compile(matrix: SparseMatrix, target: Optional[Target] = None,
                                    balance=target.balance,
                                    graph_for=lambda m: graph,
                                    backend=target.backend,
-                                   interpret=target.interpret,
                                    storage_dtype=target.dtype)
         elif budget is None:
             sprog = shard_map_spmv(matrix, target.mesh,
@@ -779,7 +778,6 @@ def compile(matrix: SparseMatrix, target: Optional[Target] = None,
                                    mode=target.partition,
                                    balance=target.balance,
                                    backend=target.backend,
-                                   interpret=target.interpret,
                                    storage_dtype=target.dtype)
         else:
             if isinstance(budget, ShardedSearchConfig):
@@ -788,7 +786,7 @@ def compile(matrix: SparseMatrix, target: Optional[Target] = None,
                 dcfg = dataclasses.replace(
                     budget, axis_name=target.axis_name,
                     mode=target.partition, balance=target.balance,
-                    backend=target.backend, interpret=target.interpret)
+                    backend=target.backend)
                 if strategy is not None:
                     dcfg = dataclasses.replace(dcfg, strategy=strategy)
             else:
@@ -798,7 +796,6 @@ def compile(matrix: SparseMatrix, target: Optional[Target] = None,
                                            search=_as_search_config(
                                                budget, target),
                                            backend=target.backend,
-                                           interpret=target.interpret,
                                            strategy=strategy)
             search_result = dist_search(matrix, target.mesh, dcfg,
                                         cache=cache)

@@ -120,6 +120,8 @@ def main(argv=None) -> int:
     parser = _build_parser()
     args = parser.parse_args(argv)
 
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     if args.train_from_store:
         if not args.store:
             parser.error("--train-from-store requires --store DIR")

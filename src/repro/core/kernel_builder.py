@@ -9,7 +9,8 @@ output vector.
 
 Two backends share one plan:
   * ``jax``    — pure-jnp program (the oracle; also what we time on CPU).
-  * ``pallas`` — the TPU kernels in ``repro.kernels`` (interpret=True on CPU).
+  * ``pallas`` — the TPU kernels in ``repro.kernels`` (Mosaic on a TPU, the
+    Pallas interpreter elsewhere — ``repro.runtime.resolve_interpret``).
 
 Since the compile-API redesign the generator is two explicit stages:
 
@@ -17,7 +18,7 @@ Since the compile-API redesign the generator is two explicit stages:
    and emits a JSON-able *kernel spec* — the complete static description of
    the generated program (step kinds, column models, combine plans,
    geometry). Nothing the kernel needs lives in Python closures anymore.
-2. ``build_kernel(spec, backend, interpret)`` interprets the spec into the
+2. ``build_kernel(spec, backend)`` interprets the spec into the
    runnable ``fn(fmt, x)``.
 
 That split is what makes ``repro.SpmvPlan`` a portable artifact: the spec
@@ -32,15 +33,15 @@ a 1-D x takes the classic SpMV path. The dispatch happens at trace time
 (``x.ndim`` is static), so both ranks jit-compile independently.
 
 Model-Driven Format Compression (``compress.py``) runs here: fitted arrays
-are elided from the stored format and recomputed in-kernel; an affine rowmap
-upgrades the combine to GRID_ACC (direct output writes, no scatter).
+are elided from the stored format and recomputed in-kernel, and an affine
+rowmap is elided too (the rows are ``b0 + arange``).
 
-Fused-combine megatiles (pallas backend): when a step's output rows are
-provably contiguous — affine slope-1 rowmap for ELL, per-tile ascending
-row runs for the seg family — the step is marked ``fused`` and the
-generated kernel owns the whole combine: the output vector is one
-revisited resident block, ``tiles_per_step`` format tiles are processed
-per grid step, and the post-hoc ``jnp`` scatter pass disappears.
+Fused combine (pallas backend): when a step's output rows are provably
+contiguous — affine slope-1 rowmap for ELL, per-tile ascending row runs
+for the seg family — the step is marked ``fused`` and the post-hoc
+``jnp`` scatter pass disappears: an ELL step's row slab lands in y by one
+slice add, and the seg kernel accumulates into a resident y block.
+``tiles_per_step`` format tiles are processed per grid step (megatile).
 
 Mixed-precision storage: ``storage_dtype="bfloat16"`` stores vals as bf16
 (and explicit cols arrays as int16 when ``n_cols`` fits), recorded per
@@ -56,6 +57,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.runtime import resolve_interpret
 
 from . import compress
 from .deprecation import warn_once
@@ -95,7 +98,6 @@ class SpmvProgram:
     descriptor: dict              # structural report (kernels, combines, fits)
     spec: dict = None             # JSON-able kernel spec (see plan_format)
     backend: str = "jax"
-    interpret: bool = True
 
     def __call__(self, x):
         return self.fn(self.fmt, x)
@@ -164,16 +166,16 @@ def _plan_ell_block(bi: int, block: Block, fmt: dict,
 
         # --- model-driven compression: rowmap -> combine upgrade ---
         affine = compress.affine_rowmap(bucket.rowmap) if do_compress else None
-        want_direct = (block.reduce.combine == "grid_acc")
         if affine is not None and affine[0] == 1:
+            # tile i owns rows [b0 + i*R, b0 + (i+1)*R): GRID_ACC and
+            # scatter build the same program (see _run_ell_step)
             _, b0 = affine
             nv = int((bucket.rowmap.ravel() >= 0).sum())
-            rep["combine"] = "grid_acc" if want_direct else "scatter(affine)"
+            rep["combine"] = "scatter(affine)"
             rep["rowmap"] = "elided(linear)"
-            combspec = {"mode": "affine", "direct": bool(want_direct),
-                        "b0": int(b0), "nv": nv}
+            combspec = {"mode": "affine", "b0": int(b0), "nv": nv}
         else:
-            if want_direct:
+            if block.reduce.combine == "grid_acc":
                 rep["combine"] = "scatter(grid_acc-fallback: rowmap not affine)"
             else:
                 rep["combine"] = "scatter"
@@ -281,12 +283,12 @@ def _finalize_steps(fmt: dict, steps: list, n_cols: int, storage_dtype: str,
         key = step["key"]
         if step["kind"] == "ell":
             # affine slope-1 rowmap: tile i owns rows [b0+i*R, b0+(i+1)*R)
-            # -> the fused kernel writes them in place, no combine pass
+            # -> the row slab lands in y by one slice add, no scatter
             fused = bool(fuse_combine
                          and step["combine"]["mode"] == "affine")
             step["fused"] = fused
             if fused:
-                step["report"]["combine"] = "fused(in-kernel)"
+                step["report"]["combine"] = "fused(slice-add)"
         elif step["kind"] == "seg":
             rm = np.asarray(fmt[f"{key}_rowmap"])
             if fuse_combine and rm.size and _contiguous_rowmap(rm):
@@ -369,19 +371,9 @@ def _run_ell_step(step: dict, fmt: dict, x, y, n_rows: int,
     comb = step["combine"]
     if backend == "pallas":
         from repro.kernels import ops as kops  # lazy: keeps core importable
-        if step.get("fused") and comb["mode"] == "affine":
-            # fused-combine megatile kernel: the finished (n_rows[, B])
-            # slab comes back — one vector add instead of a scatter pass
-            op = kops.ell_spmm_fused if rhs else kops.ell_spmv_fused
-            slab = op(vals, cols, x, row0=comb["b0"], n_rows=n_rows,
-                      tiles_per_step=tiles_per_step, interpret=interpret)
-            return y + slab
-        if comb["mode"] == "affine" and comb["direct"]:
-            # direct-write kernel: output slab, no scatter
-            op = kops.ell_spmm_direct if rhs else kops.ell_spmv_direct
-        else:
-            op = kops.ell_spmm if rhs else kops.ell_spmv
-        partial = op(vals, cols, x, interpret=interpret)
+        op = kops.ell_spmm if rhs else kops.ell_spmv
+        partial = op(vals, cols, x, tiles_per_step=tiles_per_step,
+                     interpret=interpret)
     elif rhs:
         partial = jnp.einsum("trw,trwb->trb", _f32(vals),
                              _f32(x[cols.astype(jnp.int32)]))
@@ -394,7 +386,8 @@ def _run_ell_step(step: dict, fmt: dict, x, y, n_rows: int,
         safe = jnp.where(rm >= 0, rm, n_rows)
         return y.at[safe].add(flat, mode="drop")
     b0, nv = comb["b0"], comb["nv"]
-    if comb["direct"]:
+    if step.get("fused"):
+        # the row slab is y[b0:b0 + nv]: a dense slice add
         return y.at[b0:b0 + nv].add(flat[:nv])
     idx = b0 + jnp.arange(nv, dtype=jnp.int32)
     return y.at[idx].add(flat[:nv])
@@ -462,9 +455,12 @@ def run_spec_step(step: dict, fmt: dict, x, y, n_rows: int,
                          tiles_per_step)
 
 
-def build_kernel(spec: dict, backend: str = "jax",
-                 interpret: bool = True) -> Callable:
-    """Stage 2: interpret a kernel spec into the runnable ``fn(fmt, x)``."""
+def build_kernel(spec: dict, backend: str = "jax") -> Callable:
+    """Stage 2: interpret a kernel spec into the runnable ``fn(fmt, x)``.
+
+    Pallas kernels lower through Mosaic on a TPU and run in the Pallas
+    interpreter elsewhere (``repro.runtime.resolve_interpret``)."""
+    interpret = resolve_interpret()
     n_rows = spec["n_rows"]
     steps = spec["steps"]
     tiles_per_step = int(spec.get("tiles_per_step", 1))
@@ -483,8 +479,7 @@ def build_kernel(spec: dict, backend: str = "jax",
 
 
 def build_program(meta: MetadataSet, backend: str = "jax",
-                  interpret: bool = True, do_compress: bool = True,
-                  jit: bool = True, storage_dtype: str = None,
+                  do_compress: bool = True, jit: bool = True, storage_dtype: str = None,
                   tiles_per_step: int = None,
                   fuse_combine: bool = True) -> SpmvProgram:
     """Generate the SpMV program for a designed MetadataSet.
@@ -504,16 +499,15 @@ def build_program(meta: MetadataSet, backend: str = "jax",
                   "blocks": [s["report"] for s in spec["steps"]],
                   "padded_nnz": spec["padded_nnz"],
                   "history": meta.history}
-    run = build_kernel(spec, backend=backend, interpret=interpret)
+    run = build_kernel(spec, backend=backend)
     fn = jax.jit(run) if jit else run
     return SpmvProgram(n_rows=meta.n_rows, n_cols=meta.n_cols, nnz=meta.nnz,
                        fmt=fmt, fn=fn, descriptor=descriptor, spec=spec,
-                       backend=backend, interpret=interpret)
+                       backend=backend)
 
 
-def build_spmv(meta: MetadataSet, backend: str = "jax",
-               interpret: bool = True, do_compress: bool = True,
-               jit: bool = True) -> SpmvProgram:
+def build_spmv(meta: MetadataSet, backend: str = "jax", *,
+               do_compress: bool = True, jit: bool = True) -> SpmvProgram:
     """Deprecated alias of :func:`build_program` (old four-entrypoint API).
 
     Prefer ``repro.compile(matrix, target)`` for the full matrix-in /
@@ -523,5 +517,5 @@ def build_spmv(meta: MetadataSet, backend: str = "jax",
     warn_once("build_spmv",
               "repro.core.build_spmv is deprecated; use repro.compile("
               "matrix, target) or repro.core.build_program(meta)")
-    return build_program(meta, backend=backend, interpret=interpret,
-                         do_compress=do_compress, jit=jit)
+    return build_program(meta, backend=backend, do_compress=do_compress,
+                         jit=jit)

@@ -430,6 +430,26 @@ class LanePad(Operator):
                                 spec.label())
 
 
+# Every distinct tile width is one bucket: one kernel, one XLA gather and
+# one compile. A power-law matrix at 1M rows has ~600 distinct widths, so
+# a layout keeps at most this many.
+_MAX_ELL_BUCKETS = 16
+
+
+def _cap_buckets(widths: np.ndarray, pad_to: int) -> np.ndarray:
+    """Round tile widths up onto at most ``_MAX_ELL_BUCKETS`` distinct
+    values: a geometric ladder from the narrowest to the widest width
+    (multiples of ``pad_to``), so no tile pads by more than the ladder's
+    ratio."""
+    lo, hi = int(widths.min()), int(widths.max())
+    ratio = (hi / lo) ** (1.0 / (_MAX_ELL_BUCKETS - 1))
+    ladder = lo * ratio ** np.arange(_MAX_ELL_BUCKETS)
+    ladder = -(-np.ceil(ladder).astype(np.int64) // pad_to) * pad_to
+    ladder[-1] = hi
+    ladder = np.unique(np.minimum(ladder, hi))
+    return ladder[np.searchsorted(ladder, widths)]
+
+
 def _build_ell_layout(b: Block) -> EllTileLayout:
     n = b.n_block_rows
     R = b.tile_rows or _ceil_to(max(n, 1), 8)
@@ -441,6 +461,8 @@ def _build_ell_layout(b: Block) -> EllTileLayout:
     w_per_tile = np.maximum(_ceil_to(1, b.pad_to),
                             ((w_per_tile + b.pad_to - 1) // b.pad_to) * b.pad_to)
     w_per_tile = np.maximum(w_per_tile, 1)
+    if np.unique(w_per_tile).size > _MAX_ELL_BUCKETS:
+        w_per_tile = _cap_buckets(w_per_tile, b.pad_to)
 
     row_ptr = np.concatenate([[0], np.cumsum(lengths)]).astype(np.int64)
     pos_in_row = np.arange(b.nnz, dtype=np.int64) - row_ptr[b.rows]

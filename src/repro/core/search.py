@@ -33,6 +33,7 @@ import warnings
 from pathlib import Path
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.design.space import (CONVERTING_CHOICES,  # noqa: F401 (compat)
@@ -64,19 +65,23 @@ _structure_space = structure_space
 #                  (an inapplicable design; routine, cheap, not warned)
 #   wrong_result — the generated program ran but disagreed with the
 #                  float64 dense oracle
-#   crash        — unexpected exception while lowering or running (XLA /
-#                  Pallas lowering errors, interpreter crashes, ...)
+#   lowering     — the generated program failed to trace, lower or
+#                  compile (e.g. a Pallas kernel Mosaic refuses): a
+#                  generator bug on this platform, never routine
+#   crash        — unexpected exception while running (interpreter
+#                  crashes, injected faults, ...)
 #   oom          — MemoryError or an XLA RESOURCE_EXHAUSTED
 #   timeout      — the candidate exceeded SearchConfig.candidate_timeout_s
 #   fallback     — marker bucket: every candidate failed and the baseline
 #                  jax-backend program was substituted
-FAILURE_BUCKETS = ("invalid", "wrong_result", "crash", "oom", "timeout",
-                   "fallback")
+FAILURE_BUCKETS = ("invalid", "wrong_result", "lowering", "crash", "oom",
+                   "timeout", "fallback")
 
 # "hard" failures count toward structure quarantine (DesignSpace): a
 # structure that keeps crashing/hanging stops being proposed. "invalid"
 # does not — inapplicable designs are normal pruning residue.
-_HARD_FAILURES = frozenset({"wrong_result", "crash", "oom", "timeout"})
+_HARD_FAILURES = frozenset({"wrong_result", "lowering", "crash", "oom",
+                            "timeout"})
 
 
 class CandidateTimeout(RuntimeError):
@@ -325,6 +330,10 @@ class SearchResult:
     # True when every machine-designed candidate failed and the baseline
     # jax-backend seed program was substituted as best
     fallback: bool = False
+    # seconds the search ran past its hard deadline (SearchConfig
+    # .hard_deadline): a started compile cannot be interrupted, so one
+    # whose cost was not predictable (the search's first) can overrun
+    deadline_overrun_s: float = 0.0
 
     @property
     def n_failed_candidates(self) -> int:
@@ -364,6 +373,9 @@ class AlphaSparseSearch:
             self._x = self.rng.standard_normal(
                 matrix.n_cols).astype(np.float32)
             self._oracle = matrix.spmv_dense_oracle(self._x)
+        # every candidate is timed on the same device-resident x, so no
+        # timed call includes a host-to-device copy
+        self._x_dev = jax.device_put(self._x)
         self._memo: dict[OperatorGraph, float] = {}
         self.records: list[EvalRecord] = []
         self.failed_records: list[EvalRecord] = []
@@ -377,6 +389,14 @@ class AlphaSparseSearch:
         # under cfg.hard_deadline so per-candidate deadlines shrink with
         # the time remaining (compile(deadline_s=...) guarantee)
         self._deadline_at: Optional[float] = None
+        # built programs by content (_program_key) -> seconds: different
+        # graphs can build the same program (GRID_ACC and scatter on an
+        # affine rowmap), which is compiled and timed once
+        self._by_program: dict[str, float] = {}
+        # slowest AOT compile so far per kernel step: under a hard
+        # deadline a candidate whose estimated compile does not fit the
+        # time left is not started, since a compile cannot be interrupted
+        self._compile_s_per_step = 0.0
 
     def _space(self) -> DesignSpace:
         if self._design_space is None:
@@ -436,33 +456,15 @@ class AlphaSparseSearch:
                 check_candidate_deadline()
                 prog = build_program(meta, backend=self.cfg.backend)
                 check_candidate_deadline()
-                y = np.asarray(prog(self._x))
-                if _FAULT_HOOK is not None:
-                    hooked = _FAULT_HOOK(graph, y)
-                    if hooked is not None:
-                        y = np.asarray(hooked)
-                check_candidate_deadline()
-                if self.cfg.check_correctness:
-                    scale = np.abs(self._oracle).max() + 1e-30
-                    # bf16-stored candidates carry ~2^-8 relative storage
-                    # rounding (accumulation is still fp32); hold them to
-                    # the bf16 tolerance, not the fp32 one
-                    tol = (2e-2
-                           if prog.spec.get("storage_dtype") == "bfloat16"
-                           else 1e-3)
-                    if not np.all(np.abs(y - self._oracle)
-                                  <= tol * scale + 1e-5):
-                        # a wrong program is a failed candidate, not a
-                        # fatal error: the search moves on
-                        return self._fail(graph, structure_label,
-                                          "wrong_result")
-                # timing: min over repeats of a blocking call
-                best = math.inf
-                for _ in range(self.cfg.timing_repeats):
-                    check_candidate_deadline()
-                    t0 = time.perf_counter()
-                    prog(self._x).block_until_ready()
-                    best = min(best, time.perf_counter() - t0)
+                pkey = _program_key(prog)
+                if pkey not in self._by_program:
+                    self._by_program[pkey] = math.inf   # until it succeeds
+                    self._by_program[pkey] = self._run_program(
+                        prog, graph, structure_label)
+                best = self._by_program[pkey]
+                if not math.isfinite(best):
+                    self._memo[graph] = best
+                    return best
         except (GraphError, ValueError) as e:
             # routine inapplicability (validation/Designer rejection)
             return self._fail(graph, structure_label, "invalid", e)
@@ -484,6 +486,58 @@ class AlphaSparseSearch:
             self._best = (best, graph, prog)
         return best
 
+    def _run_program(self, prog: SpmvProgram, graph: OperatorGraph,
+                     structure_label: str) -> float:
+        """AOT-compile, check and time one built candidate (inside its
+        deadline scope); a failure returns ``_fail``'s inf."""
+        n_steps = max(len(prog.spec["steps"]), 1)
+        if self._deadline_at is not None:
+            left = self._deadline_at - time.perf_counter()
+            estimate = n_steps * self._compile_s_per_step
+            if estimate > left:
+                raise CandidateTimeout(
+                    f"estimated compile of {n_steps} kernel steps "
+                    f"({estimate:.1f}s) exceeds the {left:.1f}s left")
+        t0 = time.perf_counter()
+        try:
+            call = prog.fn.lower(prog.fmt, self._x_dev).compile()
+        except CandidateTimeout:
+            raise
+        except Exception as e:
+            # the generator produced a program this platform cannot
+            # compile: a hard failure, never "invalid"
+            return self._fail(graph, structure_label,
+                              "oom" if "RESOURCE_EXHAUSTED" in repr(e)
+                              else "lowering", e)
+        self._compile_s_per_step = max(self._compile_s_per_step,
+                                       (time.perf_counter() - t0) / n_steps)
+        check_candidate_deadline()
+        y = np.asarray(call(prog.fmt, self._x_dev))
+        if _FAULT_HOOK is not None:
+            hooked = _FAULT_HOOK(graph, y)
+            if hooked is not None:
+                y = np.asarray(hooked)
+        check_candidate_deadline()
+        if self.cfg.check_correctness:
+            scale = np.abs(self._oracle).max() + 1e-30
+            # bf16-stored candidates carry ~2^-8 relative storage rounding
+            # (accumulation is still fp32); hold them to the bf16
+            # tolerance, not the fp32 one
+            tol = (2e-2 if prog.spec.get("storage_dtype") == "bfloat16"
+                   else 1e-3)
+            if not np.all(np.abs(y - self._oracle) <= tol * scale + 1e-5):
+                # a wrong program is a failed candidate, not a fatal
+                # error: the search moves on
+                return self._fail(graph, structure_label, "wrong_result")
+        # timing: min over repeats of a blocking call
+        best = math.inf
+        for _ in range(self.cfg.timing_repeats):
+            check_candidate_deadline()
+            t0 = time.perf_counter()
+            call(prog.fmt, self._x_dev).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        return best
+
     # -- baseline fallback: the trusted CSR-style jax program --------------
     def _baseline_program(self):
         """Build and time the baseline source-format program (jax backend,
@@ -497,14 +551,14 @@ class AlphaSparseSearch:
                 try:
                     meta = run_graph(self.m, graph)
                     prog = build_program(meta, backend="jax")
-                    y = np.asarray(prog(self._x))
+                    y = np.asarray(prog(self._x_dev))
                     if self.cfg.check_correctness:
                         scale = np.abs(self._oracle).max() + 1e-30
                         if not np.all(np.abs(y - self._oracle)
                                       <= 1e-3 * scale + 1e-5):
                             continue
                     t0 = time.perf_counter()
-                    np.asarray(prog(self._x))
+                    prog(self._x_dev).block_until_ready()
                     return graph, prog, time.perf_counter() - t0
                 except (GraphError, ValueError, RuntimeError) as e:
                     last_err = e
@@ -596,6 +650,8 @@ class AlphaSparseSearch:
                 f"({dict(self.failure_counts)}); returning the baseline "
                 "jax-backend program", RuntimeWarning)
         wall = time.perf_counter() - t_start
+        overrun = (max(wall - self.cfg.max_seconds, 0.0)
+                   if self.cfg.hard_deadline else 0.0)
         # useful flops: 2*nnz per right-hand side
         gflops = 2.0 * self.m.nnz * max(self.cfg.batch_size, 1) / best_s / 1e9
         return SearchResult(best_graph=best_g, best_program=best_p,
@@ -611,7 +667,21 @@ class AlphaSparseSearch:
                             failed_records=self.failed_records,
                             failure_counts=dict(self.failure_counts),
                             n_quarantined=self.n_quarantined,
-                            fallback=fallback)
+                            fallback=fallback, deadline_overrun_s=overrun)
+
+
+def _program_key(prog: SpmvProgram) -> str:
+    """Content key of a built program: its kernel spec without provenance
+    (operator history, per-step reports) plus the packed format arrays."""
+    spec = dict(prog.spec, history=None,
+                steps=[{k: v for k, v in st.items() if k != "report"}
+                       for st in prog.spec["steps"]])
+    h = hashlib.sha1(json.dumps(spec, sort_keys=True).encode())
+    for name in sorted(prog.fmt):
+        a = np.ascontiguousarray(prog.fmt[name])
+        h.update(f"{name}:{a.dtype}:{a.shape}".encode())
+        h.update(a.reshape(-1).view(np.uint8))
+    return h.hexdigest()
 
 
 # ------------------------------ program cache ------------------------------

@@ -110,8 +110,9 @@ def run_sweep(entries: Iterable[CorpusEntry], store, budget=None,
     a failed compile up to N times with exponential backoff starting at
     ``retry_backoff_s``. ``isolate="process"`` runs each compile in a
     subprocess so a crashing candidate (segfault, OOM kill) costs one
-    entry, not the driver; requires a mesh-free target and a
-    name/None strategy (instances don't serialize)."""
+    entry, not the driver; requires a mesh-free target, a name/None
+    strategy (instances don't serialize) and, on a TPU, a caller that has
+    not touched a JAX device yet (the children need the chip)."""
     from repro.corpus.features import matrix_features
 
     if isolate not in (None, "process"):
@@ -124,6 +125,8 @@ def run_sweep(entries: Iterable[CorpusEntry], store, budget=None,
         if strategy is not None and not isinstance(strategy, str):
             raise ValueError("isolate='process' needs a strategy *name* "
                              "(or None); instances don't serialize")
+        from repro.runtime import refuse_if_chip_held
+        refuse_if_chip_held("run_sweep(isolate='process')")
 
     path = (Path(records_path) if records_path
             else Path(store.cache_dir) / RECORDS_FILENAME)

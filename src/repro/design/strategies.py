@@ -375,10 +375,10 @@ class GridStrategy(SearchStrategy):
                 if structure.label() not in top:
                     continue
                 for g in space.bind(structure, "fine"):
-                    if g not in self._seen:
-                        out.append(Proposal(g, "fine"))
                     if len(out) >= self.fine_budget:
                         return out
+                    if g not in self._seen:
+                        out.append(Proposal(g, "fine"))
             return out
         return []
 

@@ -81,9 +81,6 @@ class ShardedSearchConfig:
     # any thread); SIGALRM is only a main-thread backstop for true hangs.
     max_workers: Optional[int] = None
     backend: str = "jax"
-    # interpret=True runs backend="pallas" kernels in interpret mode inside
-    # the shard_map body (the CPU stand-in for the on-device Mosaic path)
-    interpret: bool = True
 
 
 # process-global fault-injection seam: a hook(shard) invoked at the top of
@@ -196,8 +193,7 @@ def dist_search(m: SparseMatrix, mesh,
         if r.failed:
             counts["fallback"] += 1
     program = build_sharded_spmv(shards, programs, mesh, cfg.axis_name,
-                                 backend=cfg.backend,
-                                 interpret=cfg.interpret)
+                                 backend=cfg.backend)
     return ShardedSearchResult(program=program, reports=reports,
                                failure_counts=dict(counts))
 

@@ -19,9 +19,8 @@ memory dedup" item), and the body needs no ``lax.switch``: a device just
 runs every family kernel on its slice, where tiles belonging to other
 families are empty padding (val=0, rowmap=-1) that contributes nothing.
 The body itself is ``core.kernel_builder.build_kernel`` on a synthetic
-spec, so ``backend="pallas"`` (with ``interpret``) runs the real Pallas
-kernels inside shard_map (closing the "Pallas on-device path for dist"
-item).
+spec, so ``backend="pallas"`` runs the real Pallas kernels inside
+shard_map (Mosaic on a TPU, the interpreter elsewhere).
 
 Two partition modes:
 
@@ -41,7 +40,6 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.graph import OperatorGraph, run_graph
@@ -352,24 +350,6 @@ def pack_operand_format(programs: Sequence[Optional[SpmvProgram]]
     return steps, stacks
 
 
-def stacked_call(fn: Callable, stacks: dict, x, mode: str, n_cols: int,
-                 sizes: Sequence[int], dtype=jnp.float32) -> jax.Array:
-    """Shared call path for stacked-operand programs and plans.
-
-    col mode: pad x to the uniform slice width before sharding it;
-    row mode: slice each device's padded band back to its true size.
-    """
-    x = jnp.asarray(x, dtype)
-    n_shards = max(len(sizes), 1)
-    if mode == "col":
-        width = -(-n_cols // n_shards)
-        pad = width * n_shards - n_cols
-        return fn(stacks, jnp.pad(x, ((0, pad),) + ((0, 0),)
-                                  * (x.ndim - 1)))
-    out = fn(stacks, x)          # (n_shards, R[, B]) padded row bands
-    pieces = [out[i, :size] for i, size in enumerate(sizes)]
-    return (jnp.concatenate(pieces) if pieces
-            else out[:, :0].reshape((-1,) + x.shape[1:]))
 
 
 # ------------------------------ the program --------------------------------
@@ -404,7 +384,6 @@ class ShardedSpmvProgram:
     stacks: dict = dataclasses.field(default_factory=dict)
     band_rows: int = 0               # row mode: padded per-device band size
     backend: str = "jax"
-    interpret: bool = True
     _fn: Callable = dataclasses.field(repr=False, default=None)
 
     @property
@@ -439,22 +418,26 @@ class ShardedSpmvProgram:
 
     def __call__(self, x) -> jax.Array:
         """x: (n_cols,) -> (n_rows,), or (n_cols, B) -> (n_rows, B)."""
-        return stacked_call(self._fn, self.stacks, x, self.mode,
-                            self.n_cols, [s.size for s in self.shards])
+        return self._fn(self.stacks, jnp.asarray(x, jnp.float32))
 
 
 def make_stacked_fn(steps: list, mode: str, n_out: int, mesh,
-                    axis_name: str, backend: str = "jax",
-                    interpret: bool = True) -> Callable:
-    """Jitted shard_map over the stacked-operand body.
+                    axis_name: str, sizes: Sequence[int], n_cols: int,
+                    backend: str = "jax") -> Callable:
+    """Jitted ``fn(stacks, x) -> y`` over the stacked-operand body.
 
     The body is a generated kernel (``build_kernel``) over the device's
     slice of each family stack; format arrays arrive as sharded operands,
     so nothing is baked into the executable as per-device constants.
+    ``sizes`` are the shards' true extents: col mode pads x to the
+    uniform slice width before sharding it, row mode slices each device's
+    padded band back to its size — both inside the jitted function, so
+    the result is one array whatever the mesh's axis types.
     """
+    sizes = tuple(int(v) for v in sizes)
+    n_shards = max(len(sizes), 1)
     run = build_kernel({"version": SPEC_VERSION, "n_rows": n_out,
-                        "steps": steps},
-                       backend=backend, interpret=interpret)
+                        "steps": steps}, backend=backend)
 
     def body(stacks, x):
         fmt = {k: v[0] for k, v in stacks.items()}
@@ -463,19 +446,31 @@ def make_stacked_fn(steps: list, mode: str, n_out: int, mesh,
             # the COL_DIV combine step: sum per-slice partial products —
             # identical for (n_rows,) and (n_rows, B) partials
             return jax.lax.psum(y, axis_name)
-        return y[None]
+        # row mode: every device gets all padded bands, so the un-padding
+        # below slices an unsharded array (Explicit mesh axes forbid
+        # slicing a sharded dim to a size it does not divide)
+        return jax.lax.all_gather(y, axis_name)
 
     def specs_for(stacks):
         return {k: P(axis_name) for k in stacks}
 
     x_spec = P(axis_name) if mode == "col" else P(None)
-    out_spec = P(None) if mode == "col" else P(axis_name)
+    out_spec = P(None)
 
     def fn(stacks, x):
-        mapped = shard_map(body, mesh=mesh,
-                           in_specs=(specs_for(stacks), x_spec),
-                           out_specs=out_spec, check_rep=False)
-        return mapped(stacks, x)
+        if mode == "col":
+            pad = -(-n_cols // n_shards) * n_shards - n_cols
+            x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        mapped = jax.shard_map(body, mesh=mesh,
+                               in_specs=(specs_for(stacks), x_spec),
+                               out_specs=out_spec, check_vma=False)
+        out = mapped(stacks, x)
+        if mode == "col":
+            return out
+        # (n_shards, band[, B]) padded row bands -> (n_rows[, B])
+        pieces = [out[i, :size] for i, size in enumerate(sizes)]
+        return (jnp.concatenate(pieces) if pieces
+                else out[:, :0].reshape((-1,) + x.shape[1:]))
 
     return jax.jit(fn)
 
@@ -483,13 +478,10 @@ def make_stacked_fn(steps: list, mode: str, n_out: int, mesh,
 def build_sharded_spmv(shards: Sequence[RowShard],
                        programs: Sequence[Optional[SpmvProgram]],
                        mesh, axis_name: str = "data",
-                       backend: str = "jax",
-                       interpret: bool = True) -> ShardedSpmvProgram:
+                       backend: str = "jax") -> ShardedSpmvProgram:
     """Compile per-shard programs into one SPMD stacked-operand program.
 
-    ``backend``/``interpret`` select the kernels the shard_map body runs
-    (``"pallas"`` + ``interpret=True`` is the CPU stand-in for the
-    on-device Mosaic path).
+    ``backend`` selects the kernels the shard_map body runs.
     """
     shards = list(shards)
     programs = list(programs)
@@ -512,12 +504,13 @@ def build_sharded_spmv(shards: Sequence[RowShard],
     sharding = NamedSharding(mesh, P(axis_name))
     stacks = {k: jax.device_put(v, sharding) for k, v in host_stacks.items()}
     fn = make_stacked_fn(steps, mode, n_out, mesh, axis_name,
-                         backend=backend, interpret=interpret)
+                         [s.size for s in shards], n_cols,
+                         backend=backend)
     return ShardedSpmvProgram(n_rows=n_rows, n_cols=n_cols, mode=mode,
                               shards=shards, programs=programs, mesh=mesh,
                               axis_name=axis_name, steps=steps,
                               stacks=stacks, band_rows=R, backend=backend,
-                              interpret=interpret, _fn=fn)
+                              _fn=fn)
 
 
 def shard_map_spmv(m: SparseMatrix, mesh, axis_name: str = "data",
@@ -525,7 +518,6 @@ def shard_map_spmv(m: SparseMatrix, mesh, axis_name: str = "data",
                    graph_for: Callable[[SparseMatrix], OperatorGraph]
                    = default_shard_graph,
                    backend: str = "jax",
-                   interpret: bool = True,
                    storage_dtype: str = "float32") -> ShardedSpmvProgram:
     """Search-free sharded SpMV: partition + per-shard heuristic design.
 
@@ -548,4 +540,4 @@ def shard_map_spmv(m: SparseMatrix, mesh, axis_name: str = "data",
             programs.append(build_program(meta, backend=backend, jit=False,
                                           storage_dtype=sd))
     return build_sharded_spmv(shards, programs, mesh, axis_name,
-                              backend=backend, interpret=interpret)
+                              backend=backend)

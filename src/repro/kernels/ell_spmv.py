@@ -1,54 +1,49 @@
 """Pallas TPU kernels: row-per-lane padded-tile SpMV/SpMM (ELL / SELL family).
 
-TPU mapping (DESIGN.md §2): one grid step = one tile (the paper's BMTB),
-the R tile rows land on sublanes (BMW), the W padded nnz slots land on
-lanes (BMT). The x vector is VMEM-resident for the whole kernel — for
-matrices whose x exceeds VMEM, the COL_DIV converting operator stripes x
-so each stripe fits (format-level solution to a kernel-level constraint,
-which is exactly the paper's co-design thesis).
+Format: vals/cols are (T, R, W) — T tiles of R rows, each row padded to W
+slots (val=0, col=0 in the padding).
 
-The gather ``x[cols]`` lowers through ``jnp.take`` inside the kernel; on
-CPU we validate with ``interpret=True``. Grid iteration on TPU is
-sequential per core, so the ``direct`` (GRID_ACC) variant may revisit the
-same output block across steps without races.
+Mosaic gathers only within 2-D tiles, so ``x[cols]`` is gathered by XLA
+ahead of the ``pallas_call`` and streamed into the kernel as one more
+blocked operand beside ``vals`` (x itself needs no VMEM block). The kernel
+owns the multiply-reduce: each grid step takes a band of ``8 * G`` rows
+(G = lcm(R, 128) * megatile lanes), multiplies vals by the gathered x and
+reduces every row over its W slots on the MXU (ones @ prod^T), which lands
+the row sums lane-dense as an (8, G) output block. Rows stay in tile order,
+so the output flattened IS the (T*R,) row slab; the kernel builder places
+it in y (a slice add when the rowmap is affine, else a scatter).
 
-Block shapes: vals/cols blocks are (1, R, W); choose R a multiple of 8
-(sublanes) and W a multiple of 128 (lanes) via TILE_ROW_BLOCK / LANE_PAD
-for full VREG utilisation — the search engine tunes exactly these.
+Wide rows: the W axis is a second ("arbitrary") grid axis in chunks of
+128-lane multiples sized to a VMEM budget; the output block accumulates
+across it, and the lanes past W in the last chunk are masked.
 
-Mixed precision: vals may be stored bfloat16 and cols int16 (the format
-generator narrows them when ``storage_dtype='bfloat16'``); every kernel
-upcasts in-register and accumulates in float32 — partials and outputs are
-always float32, halving format-stream traffic without losing accumulation
-precision.
+Mixed precision: vals may be stored bfloat16 and cols int16; the kernel
+upcasts in-register and accumulates in float32 — outputs are always
+float32. Every MXU contraction runs at ``Precision.HIGHEST`` so the
+reduction keeps fp32 accuracy.
 
-Multi-RHS (SpMM) variants: x arrives as an (n_cols, B) tile — column b is
-the b-th right-hand side. The format arrays stream through VMEM exactly
-once for all B columns (1/B traffic amortisation vs. vmapping the 1-RHS
-kernel), the gather widens to (R, W, B), and the per-row reduction becomes
-a batched (R,W)x(R,W,B)->(R,B) ``dot_general`` contraction that the TPU
-routes through the MXU instead of the VPU.
-
-Fused-combine megatile variants (``*_fused``): the whole output vector is
-one revisited block (index_map ``t -> 0``) that stays resident across the
-sequential grid; each step processes ``tiles_per_step`` format tiles (the
-megatile — one x read and one output block amortised over K tiles) and
-writes its rows in place via ``pl.ds``, so the post-hoc scatter/add pass
-over tile partials disappears — the kernel owns the whole SpMV. Valid
-when Model-Driven Compression proved the rowmap affine with slope 1
-(tile t*K+k owns rows [row0 + (t*K+k)*R, ...)); the kernel builder
-checks and falls back to the scatter combine otherwise.
+Multi-RHS (SpMM): x is (n_cols, B); the gathered operand is (B, T*R, W)
+and the kernel loops over B inside each grid step, so the vals block is
+read from HBM once for all B right-hand sides.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ell_spmv_pallas", "ell_spmv_direct_pallas", "ell_spmv_fused_pallas",
-           "ell_spmm_pallas", "ell_spmm_direct_pallas", "ell_spmm_fused_pallas"]
+__all__ = ["ell_spmv_pallas", "ell_spmm_pallas", "vmem_limit", "HIGHEST"]
+
+HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = 128
+_SUBLANES = 8
+# VMEM the double-buffered input blocks of one grid step may take; the
+# scoped limit handed to Mosaic is derived from the blocks actually chosen
+_BLOCK_BUDGET = 8 * 1024 * 1024
 
 
 def _f32(a):
@@ -56,251 +51,126 @@ def _f32(a):
     return a.astype(jnp.float32)
 
 
-def _i32(a):
-    """Upcast (possibly int16-stored) indices for the gather."""
-    return a.astype(jnp.int32)
+def _ceil_to(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
 
 
-def _ell_kernel(x_ref, vals_ref, cols_ref, out_ref):
-    """One tile: out[r] = sum_w vals[r, w] * x[cols[r, w]]."""
-    vals = _f32(vals_ref[0])        # (R, W)
-    cols = _i32(cols_ref[0])        # (R, W)
-    x = x_ref[...]                  # (n_cols,) VMEM-resident
-    gathered = _f32(jnp.take(x, cols, axis=0))
-    out_ref[0, :] = jnp.sum(vals * gathered, axis=1)
+def vmem_limit(block_bytes: int) -> int:
+    """Scoped-VMEM limit for a kernel whose blocks take ``block_bytes``:
+    room for them plus compiler temporaries, never below the 32 MiB
+    default nor above what one v5e/v6e core has."""
+    return int(min(max(2 * block_bytes + (16 << 20), 32 << 20), 100 << 20))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ell_spmv_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array,
-                    interpret: bool = True) -> jax.Array:
-    """vals, cols: (T, R, W); x: (n_cols,) -> fp32 partials (T, R)."""
-    T, R, W = vals.shape
-    n_cols = x.shape[0]
-    return pl.pallas_call(
-        _ell_kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((n_cols,), lambda t: (0,)),       # x: whole vector
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),  # vals tile
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),  # cols tile
-        ],
-        out_specs=pl.BlockSpec((1, R), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, R), jnp.float32),
-        interpret=interpret,
-    )(x, vals, cols)
+def gather_rows(x, cols):
+    """XLA gather ahead of the kernel: x[cols] as (B, *cols.shape).
+
+    A 1-D x gives B = 1; an (n_cols, B) x gives its B columns leading."""
+    idx = cols.astype(jnp.int32)
+    if x.ndim == 1:
+        return jnp.take(x, idx, axis=0)[None]
+    return jnp.take(x.T, idx, axis=1)
 
 
-def _ell_direct_kernel(x_ref, vals_ref, cols_ref, y_ref):
-    """GRID_ACC variant: write the output rows of this tile directly.
+def _geometry(R: int, W: int, nb: int, k: int, itemsize: int):
+    """Lanes per output row G, W chunk Wc, and the step's block bytes."""
+    base = R * _LANES // math.gcd(R, _LANES)          # lcm(R, 128)
+    wc = W if W <= _LANES else _LANES
+    lane_w = _ceil_to(wc, _LANES)
 
-    Valid only when Model-Driven Compression proved the rowmap affine with
-    slope 1 (tile t owns rows [t*R, (t+1)*R)) — the kernel builder checks.
-    """
-    vals = _f32(vals_ref[0])
-    cols = _i32(cols_ref[0])
-    x = x_ref[...]
-    y_ref[...] = jnp.sum(vals * _f32(jnp.take(x, cols, axis=0)), axis=1)
+    def step_bytes(g, w):
+        return 2 * _SUBLANES * g * _ceil_to(w, _LANES) * (itemsize + 4 * nb)
 
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ell_spmv_direct_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array,
-                           interpret: bool = True) -> jax.Array:
-    """Direct-write variant -> flat (T*R,) output slab (no scatter)."""
-    T, R, W = vals.shape
-    n_cols = x.shape[0]
-    return pl.pallas_call(
-        _ell_direct_kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((n_cols,), lambda t: (0,)),
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((R,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((T * R,), jnp.float32),
-        interpret=interpret,
-    )(x, vals, cols)
+    k = max(int(k), 1)
+    while k > 1 and step_bytes(base * k, lane_w) > _BLOCK_BUDGET:
+        k //= 2
+    G = base * k
+    if W > _LANES:
+        # widest 128-multiple chunk that keeps the step in budget
+        per_lane = step_bytes(G, _LANES) // _LANES
+        wc = max(_LANES, min(_ceil_to(W, _LANES),
+                             (_BLOCK_BUDGET // max(per_lane, 1))
+                             // _LANES * _LANES))
+        if wc >= W:
+            wc = W
+    return G, wc, step_bytes(G, wc)
 
 
-# ----------------------------- multi-RHS (SpMM) -----------------------------
+def _ell_rows_kernel(vals_ref, xg_ref, out_ref, *, G: int, W: int, Wc: int,
+                     nb: int):
+    """One band of 8*G rows x one W chunk: out[b, g, :] += row sums."""
+    j = pl.program_id(1)
 
-def _ell_spmm_contract(vals, cols, x):
-    """out[r, b] = sum_w vals[r, w] * x[cols[r, w], b].
-
-    One gather of the (n_cols, B) activation tile -> (R, W, B), then a
-    batched-over-R contraction of W against B on the MXU. Accumulates and
-    returns in float32 whatever the storage dtypes.
-    """
-    gathered = jnp.take(x, _i32(cols), axis=0)    # (R, W, B)
-    return jax.lax.dot_general(
-        _f32(vals), _f32(gathered), (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-
-
-def _ell_spmm_kernel(x_ref, vals_ref, cols_ref, out_ref):
-    """One tile, all B right-hand sides: out (1, R, B)."""
-    out_ref[0] = _ell_spmm_contract(vals_ref[0], cols_ref[0], x_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ell_spmm_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array,
-                    interpret: bool = True) -> jax.Array:
-    """vals, cols: (T, R, W); x: (n_cols, B) -> fp32 partials (T, R, B)."""
-    T, R, W = vals.shape
-    n_cols, B = x.shape
-    return pl.pallas_call(
-        _ell_spmm_kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((n_cols, B), lambda t: (0, 0)),   # x: whole tile
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, R, B), lambda t: (t, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, R, B), jnp.float32),
-        interpret=interpret,
-    )(x, vals, cols)
-
-
-def _ell_spmm_direct_kernel(x_ref, vals_ref, cols_ref, y_ref):
-    """GRID_ACC SpMM variant: write this tile's (R, B) output rows directly.
-
-    Same affine-rowmap precondition as the 1-RHS direct kernel.
-    """
-    y_ref[...] = _ell_spmm_contract(vals_ref[0], cols_ref[0], x_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ell_spmm_direct_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array,
-                           interpret: bool = True) -> jax.Array:
-    """Direct-write SpMM variant -> (T*R, B) output slab (no scatter)."""
-    T, R, W = vals.shape
-    n_cols, B = x.shape
-    return pl.pallas_call(
-        _ell_spmm_direct_kernel,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((n_cols, B), lambda t: (0, 0)),
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, R, W), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((R, B), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((T * R, B), jnp.float32),
-        interpret=interpret,
-    )(x, vals, cols)
-
-
-# ----------------------- fused-combine megatile kernels ----------------------
-
-def _ell_fused_kernel(x_ref, vals_ref, cols_ref, y_ref, *, row0: int):
-    """Megatile step: K tiles' rows written straight into the resident y.
-
-    The output block is the WHOLE y vector, revisited by every grid step
-    (index_map t -> 0): TPU grid iteration is sequential per core, so the
-    block stays resident and step t may read what step t-1 wrote. Step 0
-    zeroes it; each step then writes its K*R rows in place — the combine
-    lives inside the kernel, no second pass over tile partials.
-    """
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
+    @pl.when(j == 0)
     def _init():
-        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    K, R, _ = vals_ref.shape
-    x = x_ref[...]
-    for k in range(K):                      # static unroll: the megatile
-        vals = _f32(vals_ref[k])
-        cols = _i32(cols_ref[k])
-        partial = jnp.sum(vals * _f32(jnp.take(x, cols, axis=0)), axis=1)
-        # affine slope-1 rowmap: tile t*K+k owns exactly these R rows
-        y_ref[pl.ds(row0 + (t * K + k) * R, R)] = partial
+    vals = _f32(vals_ref[...])                            # (8G, Wc)
+    mask = None
+    if W % Wc:
+        # last chunk runs past W: those lanes hold no format data
+        lane = j * Wc + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+        mask = lane < W
+    ones = jnp.ones((_SUBLANES, vals.shape[1]), jnp.float32)
 
+    def one_rhs(b, carry):
+        prod = vals * _f32(xg_ref[b])
+        if mask is not None:
+            prod = jnp.where(mask, prod, 0.0)
+        # (8, Wc) x (8G, Wc)^T: every result row holds all 8G row sums,
+        # lane-dense; row band g is lanes [g*G, (g+1)*G)
+        tot = jax.lax.dot_general(ones, prod, (((1,), (1,)), ((), ())),
+                                  precision=HIGHEST,
+                                  preferred_element_type=jnp.float32)
+        for g in range(_SUBLANES):
+            out_ref[b, g:g + 1, :] += tot[0:1, g * G:(g + 1) * G]
+        return carry
 
-def _ell_spmm_fused_kernel(x_ref, vals_ref, cols_ref, y_ref, *, row0: int):
-    """Fused megatile SpMM: same scheme, (R, B) row blocks per tile."""
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
-
-    K, R, _ = vals_ref.shape
-    x = x_ref[...]
-    for k in range(K):
-        partial = _ell_spmm_contract(vals_ref[k], cols_ref[k], x)
-        y_ref[pl.ds(row0 + (t * K + k) * R, R), :] = partial
-
-
-def _pad_tiles(vals, cols, K):
-    """Round the tile count up to a multiple of K with all-zero padding
-    tiles (val=0 -> zero partials written into rows past the real slab)."""
-    T = vals.shape[0]
-    Tp = -(-T // K) * K
-    if Tp != T:
-        pad = ((0, Tp - T),) + ((0, 0),) * (vals.ndim - 1)
-        vals = jnp.pad(vals, pad)
-        cols = jnp.pad(cols, pad)
-    return vals, cols, Tp
+    jax.lax.fori_loop(0, nb, one_rhs, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("row0", "n_rows",
-                                             "tiles_per_step", "interpret"))
-def ell_spmv_fused_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array,
-                          *, n_rows: int, row0: int = 0,
-                          tiles_per_step: int = 1,
-                          interpret: bool = True) -> jax.Array:
-    """Fused-combine SpMV: (T, R, W) tiles -> the finished (n_rows,) y.
-
-    Requires the affine slope-1 rowmap (rows row0 + i*R + r). Processes
-    ``tiles_per_step`` tiles per grid step; the output vector is one
-    revisited VMEM-resident block, so no scatter/add pass remains outside
-    the kernel.
-    """
+def _ell_rows(vals, cols, x, *, tiles_per_step: int, interpret: bool):
+    """Row slab of an ELL bucket: (T*R,) for 1-D x, (T*R, B) for (n, B)."""
     T, R, W = vals.shape
-    # clamp: a short bucket must not be padded past its own tile count
-    # (T=1 megatiled by 4 would quadruple its work)
-    K = max(min(int(tiles_per_step), T), 1)
-    vals, cols, Tp = _pad_tiles(vals, cols, K)
-    ny = max(int(n_rows), row0 + Tp * R)
-    n_cols = x.shape[0]
+    N = T * R
+    xg = gather_rows(x, cols).reshape(-1, N, W)           # (B, N, W)
+    nb = xg.shape[0]
+    G, Wc, blk = _geometry(R, W, nb, tiles_per_step, vals.dtype.itemsize)
+    rows = _SUBLANES * G
+    n_steps = pl.cdiv(N, rows)
     out = pl.pallas_call(
-        functools.partial(_ell_fused_kernel, row0=row0),
-        grid=(Tp // K,),
-        in_specs=[
-            pl.BlockSpec((n_cols,), lambda t: (0,)),
-            pl.BlockSpec((K, R, W), lambda t: (t, 0, 0)),
-            pl.BlockSpec((K, R, W), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((ny,), lambda t: (0,)),   # revisited block
-        out_shape=jax.ShapeDtypeStruct((ny,), jnp.float32),
+        functools.partial(_ell_rows_kernel, G=G, W=W, Wc=Wc, nb=nb),
+        grid=(n_steps, pl.cdiv(W, Wc)),
+        in_specs=[pl.BlockSpec((rows, Wc), lambda i, j: (i, j)),
+                  pl.BlockSpec((nb, rows, Wc), lambda i, j: (0, i, j))],
+        out_specs=pl.BlockSpec((nb, _SUBLANES, G), lambda i, j: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, n_steps * _SUBLANES, G),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(blk)),
         interpret=interpret,
-    )(x, vals, cols)
-    return out[:n_rows]
+    )(vals.reshape(N, W), xg)
+    slab = out.reshape(nb, -1)[:, :N]
+    return slab[0] if x.ndim == 1 else slab.T
 
 
-@functools.partial(jax.jit, static_argnames=("row0", "n_rows",
-                                             "tiles_per_step", "interpret"))
-def ell_spmm_fused_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array,
-                          *, n_rows: int, row0: int = 0,
-                          tiles_per_step: int = 1,
-                          interpret: bool = True) -> jax.Array:
-    """Fused-combine SpMM: x (n_cols, B) -> the finished (n_rows, B) y."""
-    T, R, W = vals.shape
-    K = max(min(int(tiles_per_step), T), 1)
-    vals, cols, Tp = _pad_tiles(vals, cols, K)
-    ny = max(int(n_rows), row0 + Tp * R)
-    n_cols, B = x.shape
-    out = pl.pallas_call(
-        functools.partial(_ell_spmm_fused_kernel, row0=row0),
-        grid=(Tp // K,),
-        in_specs=[
-            pl.BlockSpec((n_cols, B), lambda t: (0, 0)),
-            pl.BlockSpec((K, R, W), lambda t: (t, 0, 0)),
-            pl.BlockSpec((K, R, W), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((ny, B), lambda t: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ny, B), jnp.float32),
-        interpret=interpret,
-    )(x, vals, cols)
-    return out[:n_rows]
+@functools.partial(jax.jit, static_argnames=("tiles_per_step", "interpret"))
+def ell_spmv_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array, *,
+                    tiles_per_step: int = 1, interpret: bool) -> jax.Array:
+    """vals, cols: (T, R, W); x: (n_cols,) -> fp32 row sums (T, R).
+
+    Each grid step covers ``tiles_per_step`` times the minimal row band
+    (the megatile), capped by the VMEM budget."""
+    T, R, _ = vals.shape
+    return _ell_rows(vals, cols, x, tiles_per_step=tiles_per_step,
+                     interpret=interpret).reshape(T, R)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles_per_step", "interpret"))
+def ell_spmm_pallas(vals: jax.Array, cols: jax.Array, x: jax.Array, *,
+                    tiles_per_step: int = 1, interpret: bool) -> jax.Array:
+    """vals, cols: (T, R, W); x: (n_cols, B) -> fp32 row sums (T, R, B)."""
+    T, R, _ = vals.shape
+    return _ell_rows(vals, cols, x, tiles_per_step=tiles_per_step,
+                     interpret=interpret).reshape(T, R, x.shape[1])

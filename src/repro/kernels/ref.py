@@ -12,8 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ell_spmv_ref", "ell_spmv_direct_ref", "seg_spmv_ref",
-           "ell_spmm_ref", "ell_spmm_direct_ref", "seg_spmm_ref"]
+__all__ = ["ell_spmv_ref", "seg_spmv_ref", "ell_spmm_ref", "seg_spmm_ref"]
 
 
 def _f32(a):
@@ -31,12 +30,6 @@ def ell_spmv_ref(vals: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
     Padded entries must carry val=0 (their gathered x value is ignored).
     """
     return jnp.einsum("trw,trw->tr", _f32(vals), _gather(x, cols))
-
-
-def ell_spmv_direct_ref(vals, cols, x) -> jax.Array:
-    """GRID_ACC variant: tiles map to contiguous output rows; returns the
-    flat (T*R,) output slab written directly (no scatter)."""
-    return ell_spmv_ref(vals, cols, x).reshape(-1)
 
 
 def seg_spmv_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
@@ -73,12 +66,6 @@ def ell_spmm_ref(vals: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
     """Fused multi-RHS partials: vals, cols (T, R, W); x (n_cols, B)
     -> fp32 (T, R, B). Column b of x is the b-th right-hand side."""
     return jnp.einsum("trw,trwb->trb", _f32(vals), _gather(x, cols))
-
-
-def ell_spmm_direct_ref(vals, cols, x) -> jax.Array:
-    """GRID_ACC SpMM variant -> (T*R, B) contiguous output slab."""
-    out = ell_spmm_ref(vals, cols, x)
-    return out.reshape(-1, out.shape[-1])
 
 
 def seg_spmm_ref(vals, cols, local_row, seg_end, x, seg_rows: int,

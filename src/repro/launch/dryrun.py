@@ -40,7 +40,6 @@ from repro.models import (cache_spec, decode_step, init_params, n_blocks,
                           prefill)
 from repro.train.optimizer import adamw_init
 from repro.train.step import TrainConfig, make_train_step
-from repro.launch.compat import normalize_cost_analysis
 from repro.launch.mesh import make_production_mesh
 
 # -------------------------- input specs (deliverable) ----------------------
@@ -217,7 +216,7 @@ def run_cell(cfg: ArchConfig, cell: ShapeCell, multi_pod: bool,
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
-        ca = normalize_cost_analysis(compiled.cost_analysis())
+        ca = dict(compiled.cost_analysis())
         ma = compiled.memory_analysis()
         rec.update({
             "ok": True,

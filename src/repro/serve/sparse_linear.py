@@ -170,7 +170,7 @@ def sparsify_linear_sharded(w: np.ndarray, mesh, density: float = 0.1,
 
     m = prune_magnitude(np.asarray(w), density)
     cfg = dist_config or ShardedSearchConfig()
-    target = Target(backend=cfg.backend, interpret=cfg.interpret, mesh=mesh,
+    target = Target(backend=cfg.backend, mesh=mesh,
                     axis_name=cfg.axis_name, partition=cfg.mode,
                     balance=cfg.balance)
     plan = _compile(m, target, budget=cfg if do_search else None)

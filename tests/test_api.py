@@ -215,7 +215,7 @@ def test_sharded_plan_pallas_in_shard_map(mode, small_irregular):
     """backend="pallas" (interpret) runs inside the shard_map body — the
     ROADMAP "Pallas on-device path for dist" item."""
     m = small_irregular
-    t = repro.Target(backend="pallas", interpret=True, mesh=_mesh1(),
+    t = repro.Target(backend="pallas", mesh=_mesh1(),
                      partition=mode)
     plan = repro.compile(m, t)
     for b in (1, 8):
@@ -258,17 +258,7 @@ def test_sharded_dedup_vs_closure_baseline():
     assert prog.per_device_format_bytes > 0
 
 
-# --------------------------- cost analysis compat ---------------------------
-
-def test_normalize_cost_analysis_both_shapes():
-    from repro.launch.compat import normalize_cost_analysis
-    d = {"flops": 12.0, "bytes accessed": 34.0}
-    assert normalize_cost_analysis(d) == d          # dict passthrough
-    assert normalize_cost_analysis([d]) == d        # [dict] (older jax)
-    assert normalize_cost_analysis([]) == {}
-    assert normalize_cost_analysis(None) == {}
-    assert normalize_cost_analysis((d,)) == d
-
+# ------------------------------ cost analysis -------------------------------
 
 def test_plan_cost_analysis_normalized(small_uniform):
     plan = repro.compile(small_uniform,
@@ -376,3 +366,25 @@ def test_plan_json_header_is_versioned(small_uniform, tmp_path):
     assert header["format_version"] == 1
     assert header["kind"] == "dense"
     assert header["target"]["backend"] == "jax"
+
+
+def test_plan_saved_with_interpret_key_loads(tmp_path):
+    """Plans saved when Target still carried ``interpret`` load: the key
+    is ignored, interpret mode comes from the platform."""
+    from repro.api import _atomic_savez
+    m = banded_matrix(64, 2, seed=1)
+    plan = repro.compile(m, repro.Target(backend="pallas"),
+                         graph=default_shard_graph(m))
+    p = tmp_path / "old.plan.npz"
+    plan.save(p)
+    with np.load(p) as z:
+        header = json.loads(str(z["__plan__"]))
+        arrays = {k: z[k] for k in z.files if k != "__plan__"}
+    header["target"]["interpret"] = True
+    header.pop("checksum")
+    _atomic_savez(p, header, arrays)
+    loaded = repro.load_plan(p)
+    assert loaded.target == plan.target
+    assert loaded.target.runs_interpreted        # the CPU
+    x = np.ones(m.n_cols, np.float32)
+    np.testing.assert_array_equal(np.asarray(loaded(x)), np.asarray(plan(x)))

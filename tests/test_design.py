@@ -394,3 +394,24 @@ def test_shard_walks_diverge_under_derived_seeds(small_uniform):
         orders.append([s.label() for s in strat._queue])
     assert orders[0][:4] == orders[1][:4]      # same mandatory seed pass
     assert orders[0] != orders[1]              # diverging walk after it
+
+
+def test_equal_programs_compile_once():
+    """GRID_ACC and scatter on an affine rowmap build the same program:
+    the search compiles and times it once and gives both graphs its time."""
+    from repro.core.graph import OperatorGraph
+    from repro.core.operators import OpSpec
+    m = banded_matrix(96, 2, seed=3)
+
+    def ell(combine):
+        return OperatorGraph.chain(
+            OpSpec.make("COMPRESS"), OpSpec.make("TILE_ROW_BLOCK", rows=16),
+            OpSpec.make("LANE_ROW_BLOCK"),
+            OpSpec.make("LANE_TOTAL_RED", combine=combine))
+
+    s = AlphaSparseSearch(m, SearchConfig(backend="pallas", seed=0))
+    t_scatter = s._evaluate(ell("scatter"), "ell")
+    t_grid = s._evaluate(ell("grid_acc"), "ell")
+    assert t_scatter == t_grid and np.isfinite(t_grid)
+    assert len(s._by_program) == 1 and len(s._memo) == 2
+    assert len(s.records) == 2

@@ -18,7 +18,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.configs.base import ShapeCell
-from repro.launch.compat import normalize_cost_analysis
 from repro.launch.dryrun import input_specs, lower_cell, collective_stats
 from repro.models import n_blocks
 
@@ -27,7 +26,7 @@ mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 cell = ShapeCell("tiny_train", 32, 8, "train")
 lowered = lower_cell(cfg, cell, mesh)
 compiled = lowered.compile()
-ca = normalize_cost_analysis(compiled.cost_analysis())
+ca = dict(compiled.cost_analysis())
 stats = collective_stats(compiled.as_text(), body_trip=n_blocks(cfg))
 print(json.dumps({
     "flops": float(ca.get("flops", 0.0)),

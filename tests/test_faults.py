@@ -101,6 +101,30 @@ def test_wrong_result_candidates_rejected(matrix):
                        matrix.spmv_dense_oracle(x), atol=1e-3)
 
 
+def test_lowering_failure_is_hard_not_invalid(matrix, monkeypatch):
+    """A generated program the platform refuses to compile (what Mosaic
+    raises as ValueError/NotImplementedError) is a warned hard failure."""
+    import sys
+    search_mod = sys.modules["repro.core.search"]
+    real = search_mod.build_program
+
+    class Refused:
+        def lower(self, *args):
+            raise NotImplementedError("Only 2D gather is supported")
+
+    def refusing(meta, **kw):
+        prog = real(meta, **kw)
+        if kw.get("backend") == "pallas":   # the baseline stays jax
+            prog.fn = Refused()
+        return prog
+
+    monkeypatch.setattr(search_mod, "build_program", refusing)
+    with pytest.warns(RuntimeWarning, match="LOWERING"):
+        res = run_search(matrix, _cfg(backend="pallas"))
+    assert res.failure_counts.get("lowering", 0) >= 1
+    assert res.fallback
+
+
 def test_quarantine_unit(matrix):
     space = DesignSpace(matrix, _cfg(quarantine_after=2))
     assert not space.is_quarantined("S1")
@@ -154,6 +178,41 @@ def test_compile_deadline_s_bounds_search(matrix):
     x = np.ones(matrix.n_cols, np.float32)
     assert np.allclose(np.asarray(plan(x)),
                        matrix.spmv_dense_oracle(x), atol=1e-3)
+
+
+def test_deadline_skips_compile_that_cannot_fit(matrix):
+    """Under a hard deadline a candidate whose estimated compile (kernel
+    steps x the slowest compile per step so far) exceeds the time left is
+    not started — it is a timeout, and nothing is compiled for it."""
+    from repro.core.search import AlphaSparseSearch
+    s = AlphaSparseSearch(matrix, _cfg(hard_deadline=True))
+    s._deadline_at = time.perf_counter() + 5.0
+    s._compile_s_per_step = 100.0
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        assert s._evaluate(_DEFAULT_GRAPH, "default") == math.inf
+    assert s.failure_counts == {"timeout": 1}
+    # with time to spare the same candidate compiles and is timed
+    s2 = AlphaSparseSearch(matrix, _cfg(hard_deadline=True))
+    s2._deadline_at = time.perf_counter() + 60.0
+    assert math.isfinite(s2._evaluate(_DEFAULT_GRAPH, "default"))
+    assert s2._compile_s_per_step > 0
+
+
+def test_deadline_overrun_is_recorded(matrix):
+    """A search that ends past its hard deadline says by how much; one
+    that fits reports zero."""
+    res = run_search(matrix, _cfg(max_seconds=30.0, hard_deadline=True))
+    assert res.deadline_overrun_s == 0.0
+
+    def hook(graph, y):
+        time.sleep(0.2)             # uninterruptible: no checkpoint inside
+
+    with fault_hook(hook), warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        late = run_search(matrix, _cfg(max_seconds=0.05, hard_deadline=True,
+                                       candidate_timeout_s=None))
+    assert late.deadline_overrun_s > 0.0
 
 
 def test_no_faults_means_no_behavior_change(matrix):

@@ -55,7 +55,7 @@ def _mats():
 def test_fused_combine_matches_oracle(graph, tiles):
     for name, m in _mats().items():
         meta = run_graph(m, graph)
-        fused = build_program(meta, backend="pallas", interpret=True,
+        fused = build_program(meta, backend="pallas",
                               tiles_per_step=tiles)
         assert any(s.get("fused") for s in fused.spec["steps"]), name
         assert fused.spec["tiles_per_step"] == tiles
@@ -70,7 +70,7 @@ def test_fused_spmm_matches_per_column():
     m = random_uniform_matrix(100, 90, 0.06, seed=5)
     for graph in (ELL, SEG_SCAN, SEG_ONEHOT):
         meta = run_graph(m, graph)
-        prog = build_program(meta, backend="pallas", interpret=True,
+        prog = build_program(meta, backend="pallas",
                              tiles_per_step=2)
         X = np.random.default_rng(0).standard_normal(
             (m.n_cols, 3)).astype(np.float32)
@@ -85,9 +85,9 @@ def test_fused_vs_scatter_same_numbers():
     m = powerlaw_matrix(150, 140, 5.0, 1.2, seed=7)
     meta = run_graph(m, SEG_SCAN)
     x = np.random.default_rng(0).standard_normal(m.n_cols).astype(np.float32)
-    base = build_program(meta, backend="pallas", interpret=True,
+    base = build_program(meta, backend="pallas",
                          fuse_combine=False)
-    fused = build_program(meta, backend="pallas", interpret=True,
+    fused = build_program(meta, backend="pallas",
                           tiles_per_step=4)
     assert not any(s.get("fused") for s in base.spec["steps"])
     np.testing.assert_allclose(np.asarray(base(x)), np.asarray(fused(x)),
@@ -104,7 +104,7 @@ def test_seg_fused_rejected_on_reordered_rows():
         OpSpec.make("LANE_NNZ_BLOCK", chunk=64, lanes=8),
         OpSpec.make("SEG_SCAN_RED"))
     meta = run_graph(m, graph)
-    prog = build_program(meta, backend="pallas", interpret=True,
+    prog = build_program(meta, backend="pallas",
                          tiles_per_step=2)
     assert not any(s.get("fused") for s in prog.spec["steps"])
     assert all(f"{s['key']}_r0" not in prog.fmt
@@ -132,7 +132,7 @@ def test_grid_acc_rejected_on_nonaffine_rowmap():
     for s in demoted:
         assert "grid_acc-fallback" in s["report"]["combine"]
     for backend in ("jax", "pallas"):
-        prog = build_program(meta, backend=backend, interpret=True)
+        prog = build_program(meta, backend=backend)
         assert_spmv_matches(m, prog)
 
 
@@ -217,8 +217,8 @@ def test_bf16_plan_roundtrip_bit_identical(tmp_path):
 def test_bf16_halves_stored_bytes():
     m = banded_matrix(128, 3, seed=8)
     meta = run_graph(m, ELL)
-    f32 = build_program(meta, backend="pallas", interpret=True)
-    b16 = build_program(meta, backend="pallas", interpret=True,
+    f32 = build_program(meta, backend="pallas")
+    b16 = build_program(meta, backend="pallas",
                         storage_dtype="bfloat16")
     assert b16.stored_bytes < 0.65 * f32.stored_bytes
 
@@ -254,7 +254,7 @@ def test_set_resources_knobs_reach_plan_format():
     _, spec = plan_format(meta)
     assert spec["tiles_per_step"] == 4
     assert spec["storage_dtype"] == "bfloat16"
-    prog = build_program(meta, backend="pallas", interpret=True)
+    prog = build_program(meta, backend="pallas")
     assert_spmv_matches(m, prog, rtol=2e-2)
 
 
@@ -349,10 +349,10 @@ def test_cost_features_fused_and_storage():
     i_ratio = FEATURE_NAMES.index("storage_bytes_ratio")
     m = banded_matrix(120, 3, seed=1)
     meta = run_graph(m, ELL)
-    fused = build_program(meta, backend="pallas", interpret=True, jit=False)
-    base = build_program(meta, backend="pallas", interpret=True, jit=False,
+    fused = build_program(meta, backend="pallas", jit=False)
+    base = build_program(meta, backend="pallas", jit=False,
                          fuse_combine=False)
-    b16 = build_program(meta, backend="pallas", interpret=True, jit=False,
+    b16 = build_program(meta, backend="pallas", jit=False,
                         storage_dtype="bfloat16")
     f_fused = program_features(meta, fused)
     f_base = program_features(meta, base)

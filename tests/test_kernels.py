@@ -33,17 +33,18 @@ def test_ell_kernel_sweep(t, r, w, dtype):
 
 
 @pytest.mark.parametrize("t,r,w", [(2, 8, 8), (4, 16, 5)])
-def test_ell_direct_kernel(t, r, w):
+def test_ell_megatile_kernel(t, r, w):
+    """Several tiles' row bands per grid step (tiles_per_step > 1)."""
     rng = np.random.default_rng(42)
     n_cols = 128
     vals, cols = _rand_ell(rng, t, r, w, np.float32, n_cols)
     x = rng.standard_normal(n_cols).astype(np.float32)
-    got = np.asarray(ops.ell_spmv_direct(jnp.asarray(vals), jnp.asarray(cols),
-                                         jnp.asarray(x), interpret=True))
-    want = np.asarray(ref.ell_spmv_direct_ref(jnp.asarray(vals),
-                                              jnp.asarray(cols),
-                                              jnp.asarray(x)))
-    assert got.shape == (t * r,)
+    got = np.asarray(ops.ell_spmv(jnp.asarray(vals), jnp.asarray(cols),
+                                  jnp.asarray(x), tiles_per_step=4,
+                                  interpret=True))
+    want = np.asarray(ref.ell_spmv_ref(jnp.asarray(vals), jnp.asarray(cols),
+                                       jnp.asarray(x)))
+    assert got.shape == (t, r)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -121,5 +122,30 @@ def test_pallas_backend_end_to_end(small_irregular):
          OpSpec.make("ONEHOT_MXU_RED")),
     ]:
         meta = run_graph(m, OperatorGraph.chain(*chain))
-        prog = build_spmv(meta, backend="pallas", interpret=True)
+        prog = build_spmv(meta, backend="pallas")
         assert_spmv_matches(m, prog)
+
+
+def test_ell_layout_caps_width_buckets():
+    """A skewed matrix with more distinct tile widths than the cap keeps
+    at most 16 width buckets (one kernel each), pads no tile narrower than
+    its widest row, and still computes y exactly."""
+    from repro.core.graph import OperatorGraph, run_graph
+    from repro.core.kernel_builder import build_program
+    from repro.core.matrices import powerlaw_matrix
+    from repro.core.operators import OpSpec, _MAX_ELL_BUCKETS
+    from conftest import assert_spmv_matches
+
+    m = powerlaw_matrix(2048, 2048, 8.0, 1.0, seed=5)
+    lengths = m.row_lengths()
+    assert np.unique(lengths.reshape(-1, 8).max(axis=1)).size > \
+        _MAX_ELL_BUCKETS
+    meta = run_graph(m, OperatorGraph.chain(
+        OpSpec.make("COMPRESS"), OpSpec.make("TILE_ROW_BLOCK", rows=8),
+        OpSpec.make("LANE_ROW_BLOCK"), OpSpec.make("LANE_TOTAL_RED")))
+    buckets = meta.blocks[0].layout.buckets
+    assert len(buckets) <= _MAX_ELL_BUCKETS
+    for bk in buckets:
+        rows = bk.rowmap[bk.rowmap >= 0]
+        assert lengths[rows].max() <= bk.width
+    assert_spmv_matches(m, build_program(meta, backend="jax"))

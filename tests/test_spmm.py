@@ -62,16 +62,17 @@ def test_ell_spmm_three_way(b):
 
 
 @pytest.mark.parametrize("b", [1, 3])
-def test_ell_spmm_direct_three_way(b):
+def test_ell_spmm_megatile_three_way(b):
     rng = np.random.default_rng(10 + b)
     vals, cols = _rand_ell(rng, 4, 16, 5, 128)
     x = jnp.asarray(rng.standard_normal((128, b)).astype(np.float32))
-    pallas = np.asarray(ops.ell_spmm_direct(vals, cols, x, interpret=True))
-    oracle = np.asarray(ref.ell_spmm_direct_ref(vals, cols, x))
+    pallas = np.asarray(ops.ell_spmm(vals, cols, x, tiles_per_step=2,
+                                     interpret=True))
+    oracle = np.asarray(ref.ell_spmm_ref(vals, cols, x))
     percol = np.stack(
-        [np.asarray(ref.ell_spmv_direct_ref(vals, cols, x[:, i]))
+        [np.asarray(ref.ell_spmv_ref(vals, cols, x[:, i]))
          for i in range(b)], axis=-1)
-    assert pallas.shape == (4 * 16, b)
+    assert pallas.shape == (4, 16, b)
     np.testing.assert_allclose(pallas, oracle, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(oracle, percol, rtol=1e-5, atol=1e-5)
 
@@ -139,7 +140,7 @@ def test_program_batched_matches_oracle(backend, small_irregular):
     oracle = m.spmm_dense_oracle(X)
     scale = np.abs(oracle).max() + 1e-30
     for name, g in _graphs().items():
-        prog = build_spmv(run_graph(m, g), backend=backend, interpret=True)
+        prog = build_spmv(run_graph(m, g), backend=backend)
         assert prog.supports_batch
         Y = np.asarray(prog(jnp.asarray(X)))
         assert Y.shape == (m.n_rows, 3)
