@@ -1,0 +1,7 @@
+"""idle_share: 100 x (1 - union of device-busy intervals / traced window)."""
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0 or run.trace.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
