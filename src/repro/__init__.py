@@ -62,6 +62,8 @@ _EXPORTS = {
     "corpus": None,                     # submodule, imported lazily
     "CorpusModel": "repro.corpus.model",
     "PortfolioStrategy": "repro.corpus.portfolio",
+    # in-process spans and counters (snapshot() / reset())
+    "telemetry": None,                  # submodule, imported lazily
 }
 
 __all__ = sorted(_EXPORTS)

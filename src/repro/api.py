@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.graph import OperatorGraph, run_graph
 from repro.core.kernel_builder import build_kernel, build_program
 from repro.core.matrices import SparseMatrix
@@ -234,6 +235,8 @@ def _npz_restore(prefix: str, z) -> dict:
 
 @functools.lru_cache(maxsize=256)
 def _dense_kernel(spec_json: str, backend: str):
+    # runs on a cache miss only: a new jitted program, traced on first call
+    telemetry.count("repro.plan.kernel_builds")
     spec = json.loads(spec_json)
     return jax.jit(build_kernel(spec, backend=backend))
 
@@ -301,9 +304,10 @@ class SpmvPlan:
     # -- execution ---------------------------------------------------------
     def __call__(self, x) -> jax.Array:
         """x: (n_cols,) -> (n_rows,), or (n_cols, B) -> (n_rows, B)."""
-        x = jnp.asarray(x, _x_dtype(self.target))
-        fn = _dense_kernel(self.spec_json, self.target.backend)
-        return fn(self.fmt, x)
+        with telemetry.span("repro.plan.call"):
+            x = jnp.asarray(x, _x_dtype(self.target))
+            fn = _dense_kernel(self.spec_json, self.target.backend)
+            return fn(self.fmt, x)
 
     # -- dynamic sparsity --------------------------------------------------
     def update(self, delta) -> "SpmvPlan":
@@ -487,7 +491,9 @@ class ShardedSpmvPlan:
             raise ValueError("sharded plan has no mesh attached; load with "
                              "SpmvPlan.load(path, mesh=...) or rebuild the "
                              "Target with a mesh")
-        return self._fn()(self.stacks, jnp.asarray(x, _x_dtype(self.target)))
+        with telemetry.span("repro.plan.call"):
+            return self._fn()(self.stacks,
+                              jnp.asarray(x, _x_dtype(self.target)))
 
     def update(self, delta):
         """Sharded plans do not support patch-in-place updates: a delta
@@ -578,50 +584,58 @@ def load_plan(path, mesh=None) -> Union[SpmvPlan, ShardedSpmvPlan]:
                              f"{PLAN_FORMAT_VERSION}")
         want = header.get("checksum")
         if want is not None:
-            arrays = {k: z[k] for k in z.files if k != "__plan__"}
-            got = _content_checksum(header, arrays)
+            with telemetry.span("repro.store.verify"):
+                arrays = {k: z[k] for k in z.files if k != "__plan__"}
+                got = _content_checksum(header, arrays)
             if got != want:
                 raise PlanIntegrityError(
                     f"plan {path} failed its content checksum "
                     f"(stored {want[:12]}…, computed {got[:12]}…): the "
                     "file is corrupt or was modified after save")
-        if header["kind"] == "dense":
-            fmt = _npz_restore("fmt", z)
-            fc = header.get("failure_counts")
-            return SpmvPlan(
-                fmt=fmt, spec_json=json.dumps(header["spec"]),
-                graph_json=(None if header["graph"] is None
-                            else json.dumps(header["graph"])),
-                target=_target_from_dict(header["target"]),
-                search_gflops=header.get("search_gflops"),
-                failure_counts=(None if fc is None
-                                else tuple((k, int(v)) for k, v in fc)),
-                plan_version=int(header.get("plan_version", 0)))
-        target = _target_from_dict(header["target"], mesh=mesh)
-        stacks = _npz_restore("stack", z)
-        if mesh is not None:
-            n_saved = len(header["bounds"])
-            n_mesh = dict(mesh.shape).get(target.axis_name)
-            if n_mesh != n_saved:
-                raise ValueError(
-                    f"plan {path} was compiled for {n_saved} shards but the "
-                    f"attached mesh has {n_mesh} devices on axis "
-                    f"{target.axis_name!r}; re-compile for this mesh or "
-                    "attach a matching one")
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            sharding = NamedSharding(mesh, P(target.axis_name))
-            stacks = {k: jax.device_put(v, sharding)
-                      for k, v in stacks.items()}
+        with telemetry.span("repro.store.read"):
+            return _plan_from_npz(path, z, header, mesh)
+
+
+def _plan_from_npz(path, z, header: dict,
+                   mesh) -> Union[SpmvPlan, ShardedSpmvPlan]:
+    """The plan in an open plan file, its arrays read and placed."""
+    if header["kind"] == "dense":
+        fmt = _npz_restore("fmt", z)
         fc = header.get("failure_counts")
-        return ShardedSpmvPlan(
-            stacks=stacks, steps_json=json.dumps(header["steps"]),
-            mode=header["mode"], n_rows=header["n_rows"],
-            n_cols=header["n_cols"], nnz=header["nnz"],
-            band_rows=header["band_rows"],
-            bounds=tuple(tuple(b) for b in header["bounds"]),
-            target=target, replicated_bytes=header["replicated_bytes"],
+        return SpmvPlan(
+            fmt=fmt, spec_json=json.dumps(header["spec"]),
+            graph_json=(None if header["graph"] is None
+                        else json.dumps(header["graph"])),
+            target=_target_from_dict(header["target"]),
+            search_gflops=header.get("search_gflops"),
             failure_counts=(None if fc is None
-                            else tuple((k, int(v)) for k, v in fc)))
+                            else tuple((k, int(v)) for k, v in fc)),
+            plan_version=int(header.get("plan_version", 0)))
+    target = _target_from_dict(header["target"], mesh=mesh)
+    stacks = _npz_restore("stack", z)
+    if mesh is not None:
+        n_saved = len(header["bounds"])
+        n_mesh = dict(mesh.shape).get(target.axis_name)
+        if n_mesh != n_saved:
+            raise ValueError(
+                f"plan {path} was compiled for {n_saved} shards but the "
+                f"attached mesh has {n_mesh} devices on axis "
+                f"{target.axis_name!r}; re-compile for this mesh or "
+                "attach a matching one")
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        sharding = NamedSharding(mesh, P(target.axis_name))
+        stacks = {k: jax.device_put(v, sharding)
+                  for k, v in stacks.items()}
+    fc = header.get("failure_counts")
+    return ShardedSpmvPlan(
+        stacks=stacks, steps_json=json.dumps(header["steps"]),
+        mode=header["mode"], n_rows=header["n_rows"],
+        n_cols=header["n_cols"], nnz=header["nnz"],
+        band_rows=header["band_rows"],
+        bounds=tuple(tuple(b) for b in header["bounds"]),
+        target=target, replicated_bytes=header["replicated_bytes"],
+        failure_counts=(None if fc is None
+                        else tuple((k, int(v)) for k, v in fc)))
 
 
 # -------------------------------- compile -----------------------------------
@@ -670,6 +684,7 @@ def _plan_from_program(prog, graph: Optional[OperatorGraph],
     return plan
 
 
+@telemetry.span("repro.compile")
 def compile(matrix: SparseMatrix, target: Optional[Target] = None,
             budget=None, *, graph: Optional[OperatorGraph] = None,
             strategy=None, warm_start=None, deadline_s: Optional[float] = None,
@@ -934,8 +949,11 @@ class PlanStore:
     def _path(self, key: str) -> Path:
         return self.cache_dir / f"{key}.plan.npz"
 
+    @telemetry.span("repro.store.get")
     def get(self, matrix, target, budget=None, graph=None, strategy=None):
-        path = self._path(self.key(matrix, target, budget, graph, strategy))
+        with telemetry.span("repro.store.key"):
+            key = self.key(matrix, target, budget, graph, strategy)
+        path = self._path(key)
         if not path.exists():
             self.misses += 1
             return None

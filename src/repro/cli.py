@@ -16,8 +16,10 @@ Fleet workflows (docs/API.md "Fleet compilation & learned strategy")::
 Compiles the matrix (AlphaSparse search, or the heuristic design with
 ``--no-search``), saves the plan, reloads it, verifies the loaded plan is
 bit-identical to the live one and correct against the float64 dense
-oracle, then reports wall-clock GFLOPS. Also runnable without installing:
-``PYTHONPATH=src python -m repro.cli ...``.
+oracle, then reports wall-clock GFLOPS and the process's span table
+(``repro.telemetry``: where the compile, the store and the calls spent
+their time, and which functions XLA compiled). Also runnable without
+installing: ``PYTHONPATH=src python -m repro.cli ...``.
 """
 from __future__ import annotations
 
@@ -76,6 +78,25 @@ def _build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--repeats", type=int, default=5,
                     help="timing repeats for the benchmark")
     return ap
+
+
+def _print_telemetry() -> None:
+    """The span table, the counters, then XLA's compiles by function."""
+    from repro import telemetry
+    snap = telemetry.snapshot()
+    print(f"{'span':<28} {'count':>7} {'total s':>10} {'self s':>10}")
+    for name, row in sorted(snap["spans"].items()):
+        print(f"{name:<28} {row['count']:>7} {row['total_s']:>10.3f} "
+              f"{row['self_s']:>10.3f}")
+    counters = snap["counters"]
+    by_fun = counters.pop("jax.compiles_by_fun")
+    compiles = counters.pop("jax.compiles")
+    for name, value in sorted(counters.items()):
+        print(f"{name:<28} {value:g}")
+    print(f"jax.compiles {compiles}: " + (", ".join(
+        f"{fun} {n}" for fun, n in sorted(by_fun.items(),
+                                           key=lambda kv: -kv[1]))
+        or "none"))
 
 
 def _train_from_store(store_dir: str) -> int:
@@ -205,6 +226,7 @@ def main(argv=None) -> int:
     print(f"benchmark: {best * 1e6:.1f} us/call, {gflops:.3f} GFLOPS "
           f"(B={b}, {args.backend})")
     print(loaded.describe())
+    _print_telemetry()
     return 0
 
 

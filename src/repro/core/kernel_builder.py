@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.runtime import resolve_interpret
 
 from . import compress
@@ -355,42 +356,74 @@ def plan_format(meta: MetadataSet, do_compress: bool = True, *,
     return fmt, spec
 
 
-def _f32(a):
-    return a.astype(jnp.float32)
+# Recomputed column indices feed only the gather.
+_col_model = telemetry.device_call(
+    scope="spmv.gather", static_argnums=(0, 1, 2, 3))(_col_model_expr)
+
+
+def _step_cols(step: dict, fmt: dict):
+    """A step's column indices: the stored array, or the fitted model's
+    recomputation."""
+    cspec = step["cols"]
+    if cspec["mode"] == "array":
+        return fmt[cspec["key"]]
+    return _col_model(cspec["model"], tuple(cspec["params"]), cspec["n"],
+                      tuple(cspec["shape"]))
+
+
+# The combines: each a call under the ``spmv.combine`` scope, so its
+# device ops say so (telemetry.device_call).
+
+@telemetry.device_call(scope="spmv.combine")
+def _scatter_rows(y, rowmap, partial):
+    """y[rowmap] += partial; slots with rowmap < 0 hold no row."""
+    rm = rowmap.reshape(-1)
+    safe = jnp.where(rm >= 0, rm, y.shape[0])
+    return y.at[safe].add(partial.reshape((-1,) + y.shape[1:]), mode="drop")
+
+
+@telemetry.device_call(scope="spmv.combine", static_argnums=(2, 3, 4))
+def _place_rows(y, partial, b0: int, nv: int, fused: bool):
+    """y[b0:b0 + nv] += the first nv rows of partial: a dense slice add
+    when the step is fused, else a scatter at b0 + arange(nv)."""
+    flat = partial.reshape((-1,) + y.shape[1:])[:nv]
+    if fused:
+        return y.at[b0:b0 + nv].add(flat)
+    return y.at[b0 + jnp.arange(nv, dtype=jnp.int32)].add(flat)
+
+
+@telemetry.device_call(scope="spmv.combine")
+def _add_slab(y, slab):
+    return y + slab
+
+
+@telemetry.device_call(scope="spmv.combine", static_argnums=(3,))
+def _segment_add(y, prod, rows, rows_sorted: bool):
+    """y[rows] += prod, as one segmented reduction."""
+    return y + jax.ops.segment_sum(
+        prod.reshape((-1,) + y.shape[1:]), rows, num_segments=y.shape[0],
+        indices_are_sorted=rows_sorted)
 
 
 def _run_ell_step(step: dict, fmt: dict, x, y, n_rows: int,
                   backend: str, interpret: bool, tiles_per_step: int = 1):
     rhs = x.shape[1:]
-    key = step["key"]
-    vals = fmt[f"{key}_vals"]
-    cspec = step["cols"]
-    cols = (fmt[cspec["key"]] if cspec["mode"] == "array"
-            else _col_model_expr(cspec["model"], cspec["params"],
-                                 cspec["n"], cspec["shape"]))
+    vals = fmt[f"{step['key']}_vals"]
+    cols = _step_cols(step, fmt)
     comb = step["combine"]
     if backend == "pallas":
         from repro.kernels import ops as kops  # lazy: keeps core importable
         op = kops.ell_spmm if rhs else kops.ell_spmv
         partial = op(vals, cols, x, tiles_per_step=tiles_per_step,
                      interpret=interpret)
-    elif rhs:
-        partial = jnp.einsum("trw,trwb->trb", _f32(vals),
-                             _f32(x[cols.astype(jnp.int32)]))
     else:
-        partial = jnp.einsum("trw,trw->tr", _f32(vals),
-                             _f32(x[cols.astype(jnp.int32)]))
-    flat = partial.reshape((-1,) + rhs)
+        from repro.kernels import ref as kref
+        op = kref.ell_spmm_ref if rhs else kref.ell_spmv_ref
+        partial = op(vals, cols, x)
     if comb["mode"] == "rowmap":
-        rm = fmt[comb["key"]].reshape(-1)
-        safe = jnp.where(rm >= 0, rm, n_rows)
-        return y.at[safe].add(flat, mode="drop")
-    b0, nv = comb["b0"], comb["nv"]
-    if step.get("fused"):
-        # the row slab is y[b0:b0 + nv]: a dense slice add
-        return y.at[b0:b0 + nv].add(flat[:nv])
-    idx = b0 + jnp.arange(nv, dtype=jnp.int32)
-    return y.at[idx].add(flat[:nv])
+        return _scatter_rows(y, fmt[comb["key"]], partial)
+    return _place_rows(y, partial, comb["b0"], comb["nv"],
+                       bool(step.get("fused")))
 
 
 def _run_seg_step(step: dict, fmt: dict, x, y, n_rows: int,
@@ -399,25 +432,16 @@ def _run_seg_step(step: dict, fmt: dict, x, y, n_rows: int,
     key = step["key"]
     kind = step["reduce"]
     vals = fmt[f"{key}_vals"]
-    cspec = step["cols"]
-    cols = (fmt[cspec["key"]] if cspec["mode"] == "array"
-            else _col_model_expr(cspec["model"], cspec["params"],
-                                 cspec["n"], cspec["shape"]))
+    cols = _step_cols(step, fmt)
     if kind == "gmem_atom" and backend != "pallas":
         # GMEM_ATOM_RED: one global reduction of the product stream; rows
         # stored directly in the format (padded entries carry val=0 and a
         # valid row -> no masking).
-        if rhs:
-            prod = (_f32(vals)[..., None]
-                    * _f32(x[cols.astype(jnp.int32)])).reshape((-1,) + rhs)
-        else:
-            prod = (_f32(vals)
-                    * _f32(x[cols.astype(jnp.int32)])).reshape(-1)
-        rows = fmt[f"{key}_rows"].reshape(-1)
-        return y + jax.ops.segment_sum(
-            prod, rows, num_segments=n_rows,
-            indices_are_sorted=step.get("rows_sorted", False))
-    rm = fmt[f"{key}_rowmap"]
+        from repro.kernels import ref as kref
+        v = vals.astype(jnp.float32)
+        prod = (v[..., None] if rhs else v) * kref.gather(x, cols)
+        return _segment_add(y, prod, fmt[f"{key}_rows"].reshape(-1),
+                            bool(step.get("rows_sorted", False)))
     local = fmt.get(f"{key}_local")
     seg_end = fmt.get(f"{key}_end")
     seg_rows = step["seg_rows"]
@@ -432,7 +456,7 @@ def _run_seg_step(step: dict, fmt: dict, x, y, n_rows: int,
                       seg_rows, n_rows=n_rows,
                       n_out=step.get("fused_rows", n_rows), mode=pk,
                       tiles_per_step=tiles_per_step, interpret=interpret)
-            return y + slab
+            return _add_slab(y, slab)
         op = kops.seg_spmm if rhs else kops.seg_spmv
         partial = op(vals, cols, local, seg_end, x,
                      seg_rows, mode=pk, interpret=interpret)
@@ -440,9 +464,7 @@ def _run_seg_step(step: dict, fmt: dict, x, y, n_rows: int,
         from repro.kernels import ref as kref
         op = kref.seg_spmm_ref if rhs else kref.seg_spmv_ref
         partial = op(vals, cols, local, seg_end, x, seg_rows, mode=kind)
-    rmf = rm.reshape(-1)
-    safe = jnp.where(rmf >= 0, rmf, n_rows)
-    return y.at[safe].add(partial.reshape((-1,) + rhs), mode="drop")
+    return _scatter_rows(y, fmt[f"{key}_rowmap"], partial)
 
 
 def run_spec_step(step: dict, fmt: dict, x, y, n_rows: int,
@@ -465,7 +487,8 @@ def build_kernel(spec: dict, backend: str = "jax") -> Callable:
     steps = spec["steps"]
     tiles_per_step = int(spec.get("tiles_per_step", 1))
 
-    def run(fmt, x):
+    # the name is the jitted program's: XLA calls it jit_spmv_plan
+    def spmv_plan(fmt, x):
         # trace-time dispatch: 1-D x -> SpMV kernels, (n_cols, B) -> fused
         # SpMM variants. ``rhs`` is () or (B,), appended to output shapes.
         rhs = x.shape[1:]
@@ -475,7 +498,7 @@ def build_kernel(spec: dict, backend: str = "jax") -> Callable:
                               tiles_per_step)
         return y
 
-    return run
+    return spmv_plan
 
 
 def build_program(meta: MetadataSet, backend: str = "jax",

@@ -36,6 +36,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.design.space import (CONVERTING_CHOICES,  # noqa: F401 (compat)
                                 MAPPING_IMPL_CHOICES, SEED_STRUCTURES,
                                 DesignSpace, Structure, structure_space)
@@ -417,6 +418,7 @@ class AlphaSparseSearch:
         quarantine for hard failures."""
         self._memo[graph] = math.inf
         self.failure_counts[bucket] = self.failure_counts.get(bucket, 0) + 1
+        telemetry.count(f"repro.search.failed.{bucket}")
         self.failed_records.append(
             EvalRecord(graph, math.inf, None, label, status=bucket))
         if bucket in _HARD_FAILURES:
@@ -445,6 +447,7 @@ class AlphaSparseSearch:
             remaining = self._deadline_at - time.perf_counter()
             timeout = min(timeout if timeout is not None else math.inf,
                           max(remaining, 0.05))
+        telemetry.count("repro.search.candidates")
         try:
             with _candidate_deadline(timeout):
                 # cooperative checkpoints between pipeline stages: a
@@ -452,9 +455,11 @@ class AlphaSparseSearch:
                 # the SIGALRM backstop (main thread) covers true hangs
                 graph.validate()
                 check_candidate_deadline()
-                meta = run_graph(self.m, graph)
+                with telemetry.span("repro.search.design"):
+                    meta = run_graph(self.m, graph)
                 check_candidate_deadline()
-                prog = build_program(meta, backend=self.cfg.backend)
+                with telemetry.span("repro.search.pack"):
+                    prog = build_program(meta, backend=self.cfg.backend)
                 check_candidate_deadline()
                 pkey = _program_key(prog)
                 if pkey not in self._by_program:
@@ -498,9 +503,11 @@ class AlphaSparseSearch:
                 raise CandidateTimeout(
                     f"estimated compile of {n_steps} kernel steps "
                     f"({estimate:.1f}s) exceeds the {left:.1f}s left")
+        telemetry.count("repro.search.kernel_steps", n_steps)
         t0 = time.perf_counter()
         try:
-            call = prog.fn.lower(prog.fmt, self._x_dev).compile()
+            with telemetry.span("repro.search.compile"):
+                call = prog.fn.lower(prog.fmt, self._x_dev).compile()
         except CandidateTimeout:
             raise
         except Exception as e:
@@ -512,30 +519,34 @@ class AlphaSparseSearch:
         self._compile_s_per_step = max(self._compile_s_per_step,
                                        (time.perf_counter() - t0) / n_steps)
         check_candidate_deadline()
-        y = np.asarray(call(prog.fmt, self._x_dev))
-        if _FAULT_HOOK is not None:
-            hooked = _FAULT_HOOK(graph, y)
-            if hooked is not None:
-                y = np.asarray(hooked)
-        check_candidate_deadline()
-        if self.cfg.check_correctness:
-            scale = np.abs(self._oracle).max() + 1e-30
-            # bf16-stored candidates carry ~2^-8 relative storage rounding
-            # (accumulation is still fp32); hold them to the bf16
-            # tolerance, not the fp32 one
-            tol = (2e-2 if prog.spec.get("storage_dtype") == "bfloat16"
-                   else 1e-3)
-            if not np.all(np.abs(y - self._oracle) <= tol * scale + 1e-5):
-                # a wrong program is a failed candidate, not a fatal
-                # error: the search moves on
-                return self._fail(graph, structure_label, "wrong_result")
+        with telemetry.span("repro.search.check"):
+            y = np.asarray(call(prog.fmt, self._x_dev))
+            if _FAULT_HOOK is not None:
+                hooked = _FAULT_HOOK(graph, y)
+                if hooked is not None:
+                    y = np.asarray(hooked)
+            check_candidate_deadline()
+            if self.cfg.check_correctness:
+                scale = np.abs(self._oracle).max() + 1e-30
+                # bf16-stored candidates carry ~2^-8 relative storage
+                # rounding (accumulation is still fp32); hold them to the
+                # bf16 tolerance, not the fp32 one
+                tol = (2e-2 if prog.spec.get("storage_dtype") == "bfloat16"
+                       else 1e-3)
+                if not np.all(np.abs(y - self._oracle)
+                              <= tol * scale + 1e-5):
+                    # a wrong program is a failed candidate, not a fatal
+                    # error: the search moves on
+                    return self._fail(graph, structure_label,
+                                      "wrong_result")
         # timing: min over repeats of a blocking call
         best = math.inf
-        for _ in range(self.cfg.timing_repeats):
-            check_candidate_deadline()
-            t0 = time.perf_counter()
-            call(prog.fmt, self._x_dev).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
+        with telemetry.span("repro.search.time"):
+            for _ in range(self.cfg.timing_repeats):
+                check_candidate_deadline()
+                t0 = time.perf_counter()
+                call(prog.fmt, self._x_dev).block_until_ready()
+                best = min(best, time.perf_counter() - t0)
         return best
 
     # -- baseline fallback: the trusted CSR-style jax program --------------
@@ -567,6 +578,7 @@ class AlphaSparseSearch:
             f"failed too (last error: {last_err!r})")
 
     # -- the driver loop over the SearchStrategy protocol --
+    @telemetry.span("repro.search")
     def run(self, strategy=None, warm_start=()) -> SearchResult:
         # publish this search's matrix on the evaluating thread so fault
         # hooks/diagnostics can tell concurrent (per-shard) searches apart

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import telemetry
+
 __all__ = ["ell_spmv_pallas", "ell_spmm_pallas", "vmem_limit", "HIGHEST"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -62,6 +64,7 @@ def vmem_limit(block_bytes: int) -> int:
     return int(min(max(2 * block_bytes + (16 << 20), 32 << 20), 100 << 20))
 
 
+@telemetry.device_call(scope="spmv.gather")
 def gather_rows(x, cols):
     """XLA gather ahead of the kernel: x[cols] as (B, *cols.shape).
 
@@ -138,7 +141,8 @@ def _ell_rows(vals, cols, x, *, tiles_per_step: int, interpret: bool):
     G, Wc, blk = _geometry(R, W, nb, tiles_per_step, vals.dtype.itemsize)
     rows = _SUBLANES * G
     n_steps = pl.cdiv(N, rows)
-    out = pl.pallas_call(
+    name = "ell_spmv" if x.ndim == 1 else "ell_spmm"
+    kernel = pl.pallas_call(
         functools.partial(_ell_rows_kernel, G=G, W=W, Wc=Wc, nb=nb),
         grid=(n_steps, pl.cdiv(W, Wc)),
         in_specs=[pl.BlockSpec((rows, Wc), lambda i, j: (i, j)),
@@ -150,7 +154,9 @@ def _ell_rows(vals, cols, x, *, tiles_per_step: int, interpret: bool):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit(blk)),
         interpret=interpret,
-    )(vals.reshape(N, W), xg)
+        name=name,
+    )
+    out = telemetry.device_call(name=name)(kernel)(vals.reshape(N, W), xg)
     slab = out.reshape(nb, -1)[:, :N]
     return slab[0] if x.ndim == 1 else slab.T
 

@@ -12,14 +12,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ell_spmv_ref", "seg_spmv_ref", "ell_spmm_ref", "seg_spmm_ref"]
+from repro import telemetry
+
+__all__ = ["gather", "ell_spmv_ref", "seg_spmv_ref", "ell_spmm_ref",
+           "seg_spmm_ref"]
 
 
 def _f32(a):
     return a.astype(jnp.float32)
 
 
-def _gather(x, cols):
+@telemetry.device_call(scope="spmv.gather")
+def gather(x, cols):
+    """x[cols] as fp32, shaped like cols (then B for an (n_cols, B) x)."""
     return _f32(x[cols.astype(jnp.int32)])
 
 
@@ -29,7 +34,7 @@ def ell_spmv_ref(vals: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
     vals, cols: (T, R, W); x: (n_cols,) -> fp32 partials (T, R).
     Padded entries must carry val=0 (their gathered x value is ignored).
     """
-    return jnp.einsum("trw,trw->tr", _f32(vals), _gather(x, cols))
+    return jnp.einsum("trw,trw->tr", _f32(vals), gather(x, cols))
 
 
 def seg_spmv_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
@@ -45,7 +50,7 @@ def seg_spmv_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
     Both are mathematically identical; tests assert they agree.
     """
     T = vals.shape[0]
-    prod = (_f32(vals) * _gather(x, cols)).reshape(T, -1)
+    prod = (_f32(vals) * gather(x, cols)).reshape(T, -1)
     if mode == "onehot_mxu":
         onehot = jax.nn.one_hot(local_row.reshape(T, -1).astype(jnp.int32),
                                 seg_rows, dtype=jnp.float32)
@@ -65,7 +70,7 @@ def seg_spmv_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
 def ell_spmm_ref(vals: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:
     """Fused multi-RHS partials: vals, cols (T, R, W); x (n_cols, B)
     -> fp32 (T, R, B). Column b of x is the b-th right-hand side."""
-    return jnp.einsum("trw,trwb->trb", _f32(vals), _gather(x, cols))
+    return jnp.einsum("trw,trwb->trb", _f32(vals), gather(x, cols))
 
 
 def seg_spmm_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
@@ -74,7 +79,7 @@ def seg_spmm_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
     x (n_cols, B) -> fp32 (T, M, B). Same two reduction modes as 1-RHS."""
     T = vals.shape[0]
     B = x.shape[1]
-    prod = (_f32(vals)[..., None] * _gather(x, cols)).reshape(T, -1, B)
+    prod = (_f32(vals)[..., None] * gather(x, cols)).reshape(T, -1, B)
     if mode == "onehot_mxu":
         onehot = jax.nn.one_hot(local_row.reshape(T, -1).astype(jnp.int32),
                                 seg_rows, dtype=jnp.float32)

@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import telemetry
+
 from .ell_spmv import HIGHEST, _ceil_to, gather_rows, vmem_limit
 
 __all__ = ["seg_spmv_pallas", "seg_spmm_pallas",
@@ -192,7 +194,8 @@ def _seg_partials(vals, cols, local_row, seg_end, x, seg_rows: int,
     Sb = _sub_block(S)
     blk = 2 * K * Sb * L * (vals.dtype.itemsize + 4 * _SUBLANES) \
         + 2 * K * _SUBLANES * M * 4
-    return pl.pallas_call(
+    name = f"seg_{'spmv' if x.ndim == 1 else 'spmm'}_{mode}"
+    kernel = pl.pallas_call(
         functools.partial(_partials_kernel, mode=mode, Sb=Sb, L=L, M=M, K=K),
         grid=(pl.cdiv(T, K), S // Sb),
         in_specs=[pl.BlockSpec((K, Sb, L), lambda i, j: (i, j, 0)),
@@ -204,7 +207,18 @@ def _seg_partials(vals, cols, local_row, seg_end, x, seg_rows: int,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit(blk)),
         interpret=interpret,
-    )(vals, xg, aux)
+        name=name,
+    )
+    return telemetry.device_call(name=name)(kernel)(vals, xg, aux)
+
+
+@telemetry.device_call(scope="spmv.combine", static_argnums=(2,))
+def _place_partials(part, r0, ny: int):
+    """(T, B, M) partials of tiles whose rows start at r0 -> (ny, B) y."""
+    _, nb, M = part.shape
+    rows = r0[:, None] + jnp.arange(M, dtype=jnp.int32)[None, :]
+    return jnp.zeros((ny, nb), jnp.float32).at[rows.reshape(-1)].add(
+        jnp.swapaxes(part, 1, 2).reshape(-1, nb), mode="drop")
 
 
 def _seg_fused(vals, cols, local_row, seg_end, r0, x, seg_rows: int,
@@ -223,10 +237,7 @@ def _seg_fused(vals, cols, local_row, seg_end, r0, x, seg_rows: int,
     if y_bytes > _RESIDENT_Y_BUDGET:
         part = _seg_partials(vals, cols, local_row, seg_end, x, M, mode,
                              interpret)                      # (T, B, M)
-        rows = r0[:, None] + jnp.arange(M, dtype=jnp.int32)[None, :]
-        y = jnp.zeros((ny, nb), jnp.float32).at[rows.reshape(-1)].add(
-            jnp.swapaxes(part, 1, 2).reshape(-1, nb), mode="drop")
-        y = y[:n_rows]
+        y = _place_partials(part, r0, ny)[:n_rows]
         return y[:, 0] if x.ndim == 1 else y
     xg, aux = _operands(vals, cols, local_row, seg_end, x, mode)
     K = max(min(int(tiles_per_step), T), 1)
@@ -240,7 +251,8 @@ def _seg_fused(vals, cols, local_row, seg_end, r0, x, seg_rows: int,
                   _aux_spec(aux, K, Sb, mode)],
         out_specs=pl.BlockSpec((nb, Yr, _LANES), lambda i, j, _: (0, 0, 0)),
     )
-    y = pl.pallas_call(
+    name = f"seg_{'spmv' if x.ndim == 1 else 'spmm'}_fused_{mode}"
+    kernel = pl.pallas_call(
         functools.partial(_fused_kernel, mode=mode, Sb=Sb, L=L, M=M, K=K,
                           T=T, NW=NW),
         grid_spec=grid_spec,
@@ -249,7 +261,9 @@ def _seg_fused(vals, cols, local_row, seg_end, r0, x, seg_rows: int,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_limit(blk)),
         interpret=interpret,
-    )(r0, vals, xg, aux)
+        name=name,
+    )
+    y = telemetry.device_call(name=name)(kernel)(r0, vals, xg, aux)
     y = y.reshape(nb, -1)[:, :n_rows]
     return y[0] if x.ndim == 1 else y.T
 

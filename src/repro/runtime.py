@@ -5,9 +5,11 @@
   else. An explicit bool still wins (tests pin it).
 * :func:`enable_compilation_cache` — JAX's persistent compilation cache for
   entry points (``chip_smoke.py``, ``repro-compile``). Where
-  ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this sets
-  nothing; otherwise the cache lives at the fixed ``<checkout>/.jax_cache``
-  (the path is part of the cache key, so it must not move between runs).
+  ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself; otherwise the
+  cache lives at the fixed ``<checkout>/.jax_cache`` (the path is part of
+  the cache key, so it must not move between runs). Either way source
+  locations keep one frame, so that a cache key does not depend on the
+  caller.
 * :func:`refuse_if_chip_held` — a TPU belongs to one process: a parent
   that holds it blocks every child that needs it. Code that starts such
   children calls this first.
@@ -42,10 +44,17 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
 
 def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory."""
+    import jax
+    # A Mosaic kernel's serialized body carries its ops' source locations,
+    # which by default name up to 10 caller frames: the same plan called
+    # from two call sites would then have two cache keys and compile
+    # twice. One frame keeps the key the same. (Innermost-frame locations,
+    # jax_include_full_tracebacks_in_locations=False, would too, but they
+    # drop the name stack from the device names of ops lowered in line.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     CHECKOUT_CACHE_DIR.mkdir(exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
     return str(CHECKOUT_CACHE_DIR)
