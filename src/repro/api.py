@@ -43,7 +43,7 @@ import numpy as np
 
 from repro import telemetry
 from repro.core.graph import OperatorGraph, run_graph
-from repro.core.kernel_builder import build_kernel, build_program
+from repro.core.kernel_builder import build_kernel, build_program, spec_slots
 from repro.core.matrices import SparseMatrix
 from repro.core.search import (ProgramCache, SearchConfig, SearchResult,
                                _graph_from_jsonable, _graph_to_jsonable,
@@ -238,6 +238,9 @@ def _dense_kernel(spec_json: str, backend: str):
     # runs on a cache miss only: a new jitted program, traced on first call
     telemetry.count("repro.plan.kernel_builds")
     spec = json.loads(spec_json)
+    free, gathered = spec_slots(spec)
+    telemetry.count("repro.plan.nnz_gather_free", free)
+    telemetry.count("repro.plan.nnz_gathered", gathered)
     return jax.jit(build_kernel(spec, backend=backend))
 
 
@@ -305,7 +308,11 @@ class SpmvPlan:
     def __call__(self, x) -> jax.Array:
         """x: (n_cols,) -> (n_rows,), or (n_cols, B) -> (n_rows, B)."""
         with telemetry.span("repro.plan.call"):
-            x = jnp.asarray(x, _x_dtype(self.target))
+            dtype = _x_dtype(self.target)
+            if not (isinstance(x, jax.Array) and x.dtype == dtype):
+                # an array already on the device in its dtype skips it:
+                # 10-15 us of host time on every call
+                x = jnp.asarray(x, dtype)
             fn = _dense_kernel(self.spec_json, self.target.backend)
             return fn(self.fmt, x)
 

@@ -92,7 +92,10 @@ def _print_telemetry() -> None:
     by_fun = counters.pop("jax.compiles_by_fun")
     compiles = counters.pop("jax.compiles")
     for name, value in sorted(counters.items()):
-        print(f"{name:<28} {value:g}")
+        # counts in full (slot counts pass 10**6), seconds to 6 digits
+        text = (f"{int(value)}" if float(value).is_integer()
+                else f"{value:g}")
+        print(f"{name:<28} {text}")
     print(f"jax.compiles {compiles}: " + (", ".join(
         f"{fun} {n}" for fun, n in sorted(by_fun.items(),
                                            key=lambda kv: -kv[1]))

@@ -257,7 +257,8 @@ def program_features(meta, program, batch_size: int = 1) -> np.ndarray:
             if b.reduce is not None and b.reduce.kind == "onehot_mxu":
                 mxu_macs += b.layout.vals.size * b.layout.seg_rows * bsz
         if b.reduce is not None:
-            red = red + np.array(_REDUCE_ONE_HOT[b.reduce.kind])
+            red = red + np.array(_REDUCE_ONE_HOT.get(b.reduce.kind,
+                                                     (0, 0, 0, 0)))
             comb_acc += int(b.reduce.combine == "grid_acc")
     # fused-combine savings + storage narrowing, from the kernel spec/fmt
     spec = getattr(program, "spec", None) or {}

@@ -63,10 +63,12 @@ from repro.runtime import resolve_interpret
 
 from . import compress
 from .deprecation import warn_once
-from .metadata import (Block, EllTileLayout, MetadataSet, SegTileLayout)
+from .metadata import (Block, DiagLayout, EllTileLayout, MetadataSet,
+                       SegTileLayout)
 
 __all__ = ["SpmvProgram", "build_program", "build_spmv", "plan_format",
-           "build_kernel", "register_layout_planner", "SPEC_VERSION"]
+           "build_kernel", "register_layout_planner", "spec_slots",
+           "SPEC_VERSION"]
 
 SPEC_VERSION = 2
 
@@ -234,6 +236,24 @@ def _plan_seg_block(bi: int, block: Block, fmt: dict, steps: list,
     reports.append(rep)
 
 
+def _plan_dia_block(bi: int, block: Block, fmt: dict, steps: list,
+                    reports: list, do_compress: bool):
+    """Plan one diagonal-layout block: one ``dia`` step, one array."""
+    from repro.kernels.dia_spmv import geometry  # lazy: core stays light
+    layout: DiagLayout = block.layout
+    key = f"b{bi}d"
+    fmt[f"{key}_vals"] = jnp.asarray(layout.vals)
+    n_diag = len(layout.offsets)
+    slots = n_diag * layout.n_rows
+    pad_left, x_rows = geometry(layout.n_rows, layout.offsets)
+    rep = {"kernel": "dia", "diagonals": n_diag,
+           "fill": round(block.nnz / max(slots, 1), 4), "combine": "direct"}
+    steps.append({"kind": "dia", "key": key, "offsets": list(layout.offsets),
+                  "n_rows": layout.n_rows, "pad_left": pad_left,
+                  "x_rows": x_rows, "slots": slots, "report": rep})
+    reports.append(rep)
+
+
 # Layout -> spec-step planner dispatch. Keyed on the layout *type* so an
 # out-of-tree operator that packs its own layout class can register a
 # planner (and a matching spec-step interpreter) without editing core:
@@ -256,6 +276,7 @@ def register_layout_planner(layout_cls: type, *, replace: bool = False):
 
 register_layout_planner(EllTileLayout)(_plan_ell_block)
 register_layout_planner(SegTileLayout)(_plan_seg_block)
+register_layout_planner(DiagLayout)(_plan_dia_block)
 
 
 def _contiguous_rowmap(rm: np.ndarray) -> bool:
@@ -282,6 +303,8 @@ def _finalize_steps(fmt: dict, steps: list, n_cols: int, storage_dtype: str,
     """
     for step in steps:
         key = step["key"]
+        if step["kind"] in ("ell", "seg"):
+            step["slots"] = int(np.prod(fmt[f"{key}_vals"].shape))
         if step["kind"] == "ell":
             # affine slope-1 rowmap: tile i owns rows [b0+i*R, b0+(i+1)*R)
             # -> the row slab lands in y by one slice add, no scatter
@@ -301,14 +324,15 @@ def _finalize_steps(fmt: dict, steps: list, n_cols: int, storage_dtype: str,
                 step["report"]["combine"] = "fused(carry)"
             else:
                 step["fused"] = False
-        else:
+        elif step["kind"] != "dia":
             continue
         if storage_dtype == "bfloat16":
             store = {"vals": "bfloat16"}
             fmt[f"{key}_vals"] = jnp.asarray(fmt[f"{key}_vals"],
                                              jnp.bfloat16)
-            cspec = step["cols"]
-            if cspec["mode"] == "array" and n_cols <= _INT16_MAX_COLS:
+            cspec = step.get("cols")   # a dia step stores no columns
+            if (cspec and cspec["mode"] == "array"
+                    and n_cols <= _INT16_MAX_COLS):
                 fmt[cspec["key"]] = jnp.asarray(fmt[cspec["key"]], jnp.int16)
                 store["cols"] = "int16"
             step["store"] = store
@@ -467,14 +491,55 @@ def _run_seg_step(step: dict, fmt: dict, x, y, n_rows: int,
     return _scatter_rows(y, fmt[f"{key}_rowmap"], partial)
 
 
+@telemetry.device_call(scope="spmv.gather", static_argnums=(1, 2, 3))
+def _pad_x(x, pad_left: int, x_rows: int, rows2d: bool):
+    """x zero-padded to x_rows * 128 entries, x[c] at pad_left + c (the
+    tail is cut where no diagonal reads it): the diagonal step's one copy
+    of x, as (x_rows, 128) rows for the kernel when ``rows2d``."""
+    n = x.shape[0]
+    hi = x_rows * 128 - pad_left - n
+    pad = [(pad_left, hi, 0)] + [(0, 0, 0)] * (x.ndim - 1)
+    xp = jax.lax.pad(x.astype(jnp.float32), jnp.float32(0), pad)
+    return xp.reshape(x_rows, 128) if rows2d else xp
+
+
+def _run_dia_step(step: dict, fmt: dict, x, y, backend: str,
+                  interpret: bool):
+    """A diagonal step: x read as shifted windows, y written in row order.
+    Multi-RHS x runs the reference formulation."""
+    vals = fmt[f"{step['key']}_vals"]
+    geom = dict(offsets=tuple(step["offsets"]), pad_left=step["pad_left"],
+                n_rows=step["n_rows"])
+    kernel = backend == "pallas" and x.ndim == 1
+    xp = _pad_x(x, step["pad_left"], step["x_rows"], kernel)
+    if kernel:
+        from repro.kernels import ops as kops
+        return y + kops.dia_spmv(vals, xp, interpret=interpret, **geom)
+    from repro.kernels import ref as kref
+    return y + kref.dia_spmv_ref(vals, xp, **geom)
+
+
 def run_spec_step(step: dict, fmt: dict, x, y, n_rows: int,
                   backend: str, interpret: bool, tiles_per_step: int = 1):
     """Accumulate one spec step's contribution into y (shared with dist)."""
+    if step["kind"] == "dia":
+        return _run_dia_step(step, fmt, x, y, backend, interpret)
     if step["kind"] == "ell":
         return _run_ell_step(step, fmt, x, y, n_rows, backend, interpret,
                              tiles_per_step)
     return _run_seg_step(step, fmt, x, y, n_rows, backend, interpret,
                          tiles_per_step)
+
+
+def spec_slots(spec: dict) -> tuple[int, int]:
+    """(gather-free, gathered) slots of a plan's steps: the slots of its
+    ``dia`` steps, which read x as windows, and of its ``ell`` / ``seg``
+    steps, each of which gathers one x entry (spec ``slots``; 0 in plans
+    saved before it was recorded)."""
+    free = sum(s.get("slots", 0) for s in spec["steps"] if s["kind"] == "dia")
+    gathered = sum(s.get("slots", 0) for s in spec["steps"]
+                   if s["kind"] in ("ell", "seg"))
+    return free, gathered
 
 
 def build_kernel(spec: dict, backend: str = "jax") -> Callable:

@@ -17,6 +17,8 @@ State model
 * Layouts (``EllTileLayout`` / ``SegTileLayout``) are the TPU adaptation of
   the paper's BMTB/BMW/BMT block structures: tiles -> Pallas grid steps,
   8-row panels -> sublanes, 128 slots -> lanes (DESIGN.md §2).
+  ``DiagLayout`` stores a matrix whose nonzeros lie on few diagonals by
+  diagonal (DIA): no column indices, rows in order.
 """
 from __future__ import annotations
 
@@ -33,6 +35,7 @@ __all__ = [
     "EllBucket",
     "EllTileLayout",
     "SegTileLayout",
+    "DiagLayout",
     "ReducePlan",
     "from_matrix",
 ]
@@ -112,15 +115,36 @@ class SegTileLayout:
                 + self.rowmap.nbytes + self.seg_end.nbytes)
 
 
-Layout = "EllTileLayout | SegTileLayout"
+@dataclasses.dataclass(frozen=True)
+class DiagLayout:
+    """Diagonal (DIA) layout: row r's products read x[r + offsets[d]].
+
+    ``vals``: (D, n_pad / 128, 128), diagonal d's value of row r at
+    ``vals[d].ravel()[r]``; zero where the diagonal holds no entry or runs
+    off the matrix, and on the pad rows up to ``n_pad`` (``n_rows`` rounded
+    up to whole 128-lane rows). No column index and no row map is stored.
+    """
+
+    offsets: tuple[int, ...]
+    vals: np.ndarray
+    n_rows: int
+
+    def padded_nnz(self) -> int:
+        return len(self.offsets) * self.n_rows
+
+    def stored_bytes(self) -> int:
+        return self.vals.nbytes
+
+
+Layout = "EllTileLayout | SegTileLayout | DiagLayout"
 
 
 @dataclasses.dataclass(frozen=True)
 class ReducePlan:
     """Implementing-stage decision: in-tile reduction + cross-tile combine."""
 
-    kind: str      # 'lane_total' | 'seg_scan' | 'onehot_mxu'
-    combine: str   # 'scatter' | 'grid_acc'
+    kind: str      # 'lane_total' | 'seg_scan' | 'onehot_mxu' | 'dia_sum'
+    combine: str   # 'scatter' | 'grid_acc' | 'direct' (rows in order)
     params: tuple = ()
 
 

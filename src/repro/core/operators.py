@@ -27,6 +27,8 @@ WARP_SEG_RED      SEG_SCAN_RED           in-tile segmented scan over nnz stream
 WARP_BITMAP_RED   ONEHOT_MXU_RED         one-hot matmul reduce on the MXU
 GMEM_ATOM_RED     GRID_ACC_RED combine   revisit output block across grid steps
 SHMEM_OFFSET_RED  SCATTER_RED combine    segment-sum of tile partials
+(DIA)             DIAG_BLOCK             diagonal layout: x read as shifted windows
+(DIA)             DIAG_SUM_RED           sum over diagonals, rows in order
 ================  =====================  =======================================
 """
 from __future__ import annotations
@@ -41,10 +43,11 @@ from repro.design.registry import (OPERATOR_REGISTRY, Operator, OpSpec,
                                    STAGE_CONVERTING, STAGE_IMPLEMENTING,
                                    STAGE_MAPPING, get_operator,
                                    register_operator)
-from .metadata import (Block, EllBucket, EllTileLayout, MetadataSet,
-                       ReducePlan, SegTileLayout)
+from .metadata import (Block, DiagLayout, EllBucket, EllTileLayout,
+                       MetadataSet, ReducePlan, SegTileLayout)
 
-__all__ = ["OpSpec", "OPERATORS", "apply_op", "Operator",
+__all__ = ["OpSpec", "OPERATORS", "apply_op", "Operator", "MAX_DIAGONALS",
+           "diagonal_offsets",
            "STAGE_CONVERTING", "STAGE_MAPPING", "STAGE_IMPLEMENTING"]
 
 
@@ -579,6 +582,77 @@ class LaneNnzBlock(Operator):
         return meta.with_blocks(blocks, spec.label())
 
 
+# The DIA kernel unrolls over its diagonals at trace time.
+MAX_DIAGONALS = 128
+
+# nonzeros looked at between two counts of the distinct diagonals
+_DIAG_CHUNK = 1 << 16
+
+
+def diagonal_offsets(rows: np.ndarray, cols: np.ndarray, n_rows: int,
+                     n_cols: int, limit: int = MAX_DIAGONALS):
+    """The sorted distinct ``col - row`` offsets, or None once they number
+    more than ``limit``: counted chunk by chunk, so a matrix with millions
+    of diagonals stops after its first chunk."""
+    seen = np.zeros(n_rows + n_cols, bool)
+    base = n_rows - 1
+    for lo in range(0, rows.size, _DIAG_CHUNK):
+        off = (cols[lo:lo + _DIAG_CHUNK].astype(np.int64)
+               - rows[lo:lo + _DIAG_CHUNK] + base)
+        seen[off] = True
+        if np.count_nonzero(seen) > limit:
+            return None
+    return np.flatnonzero(seen) - base
+
+
+def _build_diag_layout(b: Block, n_rows: int, n_cols: int) -> DiagLayout:
+    offsets = diagonal_offsets(b.rows, b.cols, n_rows, n_cols)
+    if offsets is None:
+        raise ValueError(f"DIAG_BLOCK: more than {MAX_DIAGONALS} diagonals")
+    base = n_rows - 1
+    slot = np.full(n_rows + n_cols, -1, np.int64)
+    slot[offsets + base] = np.arange(offsets.size)
+    n_pad = _ceil_to(max(n_rows, 1), 128)
+    vals = np.zeros((offsets.size, n_pad), np.float32)
+    d = slot[b.cols.astype(np.int64) - b.rows + base]
+    if np.any((b.rows[1:] == b.rows[:-1]) & (b.cols[1:] == b.cols[:-1])):
+        np.add.at(vals, (d, b.rows), b.vals)   # repeated (row, col): summed
+    else:
+        vals[d, b.rows] = b.vals
+    return DiagLayout(offsets=tuple(int(o) for o in offsets),
+                      vals=vals.reshape(offsets.size, n_pad // 128, 128),
+                      n_rows=n_rows)
+
+
+@register_operator("DIAG_BLOCK")
+class DiagBlock(Operator):
+    """Diagonal (DIA) layout: one value row per distinct ``col - row``.
+
+    Row r's products read x[r + offset], so over a band of rows each
+    diagonal reads a contiguous window of x: no column indices, no gather,
+    rows in order (no row map, no combine). Needs the matrix's own row
+    order in one block; offered by the DesignSpace's rule on the
+    diagonals' count and fill, not enumerated."""
+
+    name, stage = "DIAG_BLOCK", STAGE_MAPPING
+    builds_layout = "dia"
+
+    @staticmethod
+    def applicable(meta):
+        return (meta.compressed and len(meta.blocks) == 1
+                and meta.blocks[0].layout is None)
+
+    @staticmethod
+    def apply(meta, spec):
+        (b,) = meta.blocks
+        if b.col_base != 0 or not np.array_equal(b.row_ids,
+                                                 np.arange(meta.n_rows)):
+            raise ValueError("DIAG_BLOCK needs the matrix's rows in order "
+                             "in one block")
+        layout = _build_diag_layout(b, meta.n_rows, meta.n_cols)
+        return meta.with_blocks([b.replace(layout=layout)], spec.label())
+
+
 @register_operator("SET_RESOURCES")
 class SetResources(Operator):
     """Runtime knobs: lanes, fused-kernel megatile width, storage dtype.
@@ -731,6 +805,30 @@ class GmemAtomRed(Operator):
     @staticmethod
     def apply(meta, spec):
         return _set_reduce(meta, spec, "gmem_atom", SegTileLayout)
+
+
+@register_operator("DIAG_SUM_RED")
+class DiagSumRed(Operator):
+    """Sum of the diagonals' products per row; rows land in y in order."""
+
+    name, stage = "DIAG_SUM_RED", STAGE_IMPLEMENTING
+    is_reducer = True
+    accepts_layouts = ("dia",)
+
+    @staticmethod
+    def applicable(meta):
+        return all(isinstance(b.layout, DiagLayout) for b in meta.blocks)
+
+    @staticmethod
+    def apply(meta, spec):
+        blocks = []
+        for b in meta.blocks:
+            if not isinstance(b.layout, DiagLayout):
+                raise ValueError("DIAG_SUM_RED needs DiagLayout, block has "
+                                 f"{type(b.layout).__name__}")
+            blocks.append(b.replace(reduce=ReducePlan(kind="dia_sum",
+                                                      combine="direct")))
+        return meta.with_blocks(blocks, spec.label())
 
 
 # ``OPERATORS`` *is* the process-wide registry (same dict object), so

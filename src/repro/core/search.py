@@ -264,6 +264,11 @@ class SearchConfig:
     use_pruning: bool = True
     use_cost_model: bool = True
     allow_branch_mix: bool = True
+    # offer the diagonal (DIA) seed where the matrix passes the
+    # DesignSpace's rule (``repro.design.space.offers_diagonal``); the
+    # sharded search turns it off, since ``repro.dist`` packs only ELL and
+    # seg steps
+    offer_diagonal: bool = True
     backend: str = "jax"
     check_correctness: bool = True
     # number of right-hand sides the served program will see: 1 searches the

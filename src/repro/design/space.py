@@ -25,7 +25,8 @@ from .registry import (OPERATOR_REGISTRY, STAGE_CONVERTING, STAGE_MAPPING,
                        STAGE_IMPLEMENTING, _ensure_builtins, get_operator)
 
 __all__ = ["Structure", "DesignSpace", "structure_space",
-           "CONVERTING_CHOICES", "MAPPING_IMPL_CHOICES", "SEED_STRUCTURES"]
+           "CONVERTING_CHOICES", "MAPPING_IMPL_CHOICES", "SEED_STRUCTURES",
+           "DIAGONAL_SEED", "offers_diagonal"]
 
 
 # ------------------------- structure templates ----------------------------
@@ -64,10 +65,31 @@ SEED_STRUCTURES: tuple[tuple[tuple[str, ...], tuple[str, ...]], ...] = (
     ((), ("LANE_NNZ_BLOCK", "SEG_SCAN_RED")),                      # CSR5
 )
 
+# Offered after the seeds above only where ``offers_diagonal`` holds for
+# the matrix; never enumerated (its ops are base chain ops below, so the
+# registry weave skips them), so on every other matrix the space and the
+# walk stay candidate-for-candidate what they were.
+DIAGONAL_SEED: tuple[tuple[str, ...], tuple[str, ...]] = (
+    (), ("DIAG_BLOCK", "DIAG_SUM_RED"))                            # DIA
+
 _BASE_CONVERTING_OPS = frozenset(
     n for c in CONVERTING_CHOICES for n in c) | {"COMPRESS"}
 _BASE_CHAIN_OPS = frozenset(n for c in MAPPING_IMPL_CHOICES for n in c) | {
-    "SET_RESOURCES"}
+    "SET_RESOURCES", *DIAGONAL_SEED[1]}
+
+
+def offers_diagonal(matrix) -> bool:
+    """The diagonal seed's rule, read from the matrix alone: at most
+    ``MAX_DIAGONALS`` distinct ``col - row`` offsets; no more slots than
+    two per nonzero (DIA then stores no more than ELL's values plus int32
+    columns); and a zero-padded x that the kernel's VMEM budget holds."""
+    from repro.core.operators import MAX_DIAGONALS, diagonal_offsets
+    from repro.kernels.dia_spmv import x_fits_vmem
+    offsets = diagonal_offsets(matrix.rows, matrix.cols, matrix.n_rows,
+                               matrix.n_cols, limit=MAX_DIAGONALS)
+    return (offsets is not None and offsets.size > 0
+            and offsets.size * matrix.n_rows <= 2 * matrix.nnz
+            and x_fits_vmem(matrix.n_rows, offsets))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,7 +166,8 @@ class DesignSpace:
     through:
 
     * ``seed_structures()`` — the source-format fidelity floor, evaluated
-      first by every shipped strategy;
+      first by every shipped strategy, and the diagonal seed last where
+      ``offers_diagonal`` holds for the matrix;
     * ``structures()`` — the full pruned structure space (seeds included);
     * ``bind(structure, "coarse"|"fine")`` — cartesian parameter binding
       to concrete ``OperatorGraph`` candidates;
@@ -166,6 +189,7 @@ class DesignSpace:
         # further proposals — repeat offenders are data, not retries
         self._failure_counts: dict[str, int] = {}
         self.quarantined: set[str] = set()
+        self._diagonal: bool | None = None   # offers_diagonal, once
 
     # -- quarantine (fault-tolerant search) --
     def note_failure(self, label: str, bucket: str = "crash",
@@ -219,8 +243,17 @@ class DesignSpace:
 
     # -- enumeration --
     def seed_structures(self) -> list[Structure]:
+        seeds = SEED_STRUCTURES
+        if self._offers_diagonal():
+            seeds += (DIAGONAL_SEED,)
         return [Structure(("COMPRESS",) + c, (b,), shared=True)
-                for c, b in SEED_STRUCTURES]
+                for c, b in seeds]
+
+    def _offers_diagonal(self) -> bool:
+        if self._diagonal is None:
+            self._diagonal = (getattr(self.cfg, "offer_diagonal", True)
+                              and offers_diagonal(self.m))
+        return self._diagonal
 
     def structures(self) -> list[Structure]:
         return list(self._structures)

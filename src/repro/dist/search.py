@@ -221,7 +221,7 @@ def _design_shard(s: RowShard, cfg: ShardedSearchConfig,
             scfg = dataclasses.replace(
                 cfg.search,
                 seed=cfg.seed + cfg.search.seed + s.index,
-                backend=cfg.backend)
+                backend=cfg.backend, offer_diagonal=False)
             res = run_search(s.matrix, scfg, cache=cache,
                              strategy=cfg.strategy)
             return res.best_program, ShardReport(s, True,

@@ -229,6 +229,9 @@ def _shard_family_parts(program: Optional[SpmvProgram]) -> dict:
         return out
     fmt = {k: np.asarray(v) for k, v in program.fmt.items()}
     for step in program.spec["steps"]:
+        if step["kind"] not in ("ell", "seg"):
+            raise ValueError(f"sharded plans pack ell and seg steps, not "
+                             f"{step['kind']!r}")
         key = step["key"]
         vals = fmt[f"{key}_vals"]          # narrowed dtype preserved
         cols = materialize_cols(step["cols"], fmt)

@@ -21,6 +21,9 @@ value of 0 marks a free slot* (the builders zero-fill padding and
   frozen in the segment descriptors; adds can only fill a free position
   *already owned by the same row* (a prior removal, or tail padding for
   the stream's last row). Removals and revalues always fit.
+* **dia** (``DIAG_BLOCK``): slot (d, r) holds the entry on diagonal d of
+  row r; its zero slots on the matrix are free. Adds fit on a stored
+  diagonal and never on another: the offsets are the step's pattern.
 * **model-elided cols**: the column array was replaced by a fitted model
   at pack time — the pattern is frozen; only revalues and removals fit.
 * **int16 cols**: narrowing only happens when ``n_cols`` fits int16, so
@@ -85,17 +88,28 @@ def _occupancy(vals: np.ndarray) -> np.ndarray:
     return np.asarray(vals).astype(np.float32) != 0.0
 
 
+def _dia_matrix_slots(step: dict, n_cols: int) -> int:
+    """Slots of a dia step that lie on the matrix: row r of diagonal d
+    where 0 <= r + offsets[d] < n_cols."""
+    off = np.asarray(step["offsets"], np.int64)
+    n_rows = int(step["n_rows"])
+    lo = np.maximum(0, -off)
+    hi = np.minimum(n_rows, n_cols - off)
+    return int(np.maximum(hi - lo, 0).sum())
+
+
 def capacity_report(plan) -> dict:
     """Headroom metadata for every step of a dense ``SpmvPlan``.
 
     Returns a JSON-able dict: per-step occupancy/slack plus the headline
-    aggregates (``ell_slack``, ``seg_headroom``, ``frozen_steps``,
+    aggregates (``ell_slack``, ``seg_headroom``, ``dia_headroom``,
+    ``frozen_steps``,
     ``int16_col_margin``, ``live_nnz``) the capacity checker and
     ``describe()`` share."""
     spec = plan.spec
     fmt = plan.fmt
     steps_out = []
-    ell_slack = seg_headroom = live_nnz = frozen = 0
+    ell_slack = seg_headroom = dia_headroom = live_nnz = frozen = 0
     int16_margin = None
     for step in spec["steps"]:
         key = step["key"]
@@ -103,6 +117,14 @@ def capacity_report(plan) -> dict:
         occ = _occupancy(vals)
         used = int(occ.sum())
         live_nnz += used
+        if step["kind"] == "dia":        # no column array to freeze
+            free = _dia_matrix_slots(step, int(spec["n_cols"])) - used
+            dia_headroom += free
+            steps_out.append({"key": key, "kind": "dia",
+                              "mutable_cols": False, "slots": int(occ.size),
+                              "used": used, "free_slots": free,
+                              "diagonals": len(step["offsets"])})
+            continue
         mutable = step["cols"]["mode"] == "array"
         if not mutable:
             frozen += 1
@@ -139,6 +161,7 @@ def capacity_report(plan) -> dict:
     return {"plan_version": int(getattr(plan, "plan_version", 0)),
             "live_nnz": live_nnz, "birth_nnz": int(spec["nnz"]),
             "ell_slack": ell_slack, "seg_headroom": seg_headroom,
+            "dia_headroom": dia_headroom,
             "frozen_steps": frozen, "int16_col_margin": int16_margin,
             "steps": steps_out}
 
@@ -150,6 +173,8 @@ def capacity_lines(plan) -> list:
             f"(birth {rep['birth_nnz']}) ell_slack={rep['ell_slack']} "
             f"seg_headroom={rep['seg_headroom']} "
             f"version={rep['plan_version']}")
+    if any(s["kind"] == "dia" for s in rep["steps"]):
+        head += f" dia_headroom={rep['dia_headroom']}"
     if rep["frozen_steps"]:
         head += f" frozen_steps={rep['frozen_steps']}"
     if rep["int16_col_margin"] is not None:
@@ -158,7 +183,9 @@ def capacity_lines(plan) -> list:
     for s in rep["steps"]:
         detail = (f"    step {s['key']}: used {s['used']}/{s['slots']}"
                   f" free={s['free_slots']}")
-        if not s["mutable_cols"]:
+        if s["kind"] == "dia":
+            detail += f" diagonals={s['diagonals']}"
+        elif not s["mutable_cols"]:
             detail += " cols=frozen(model-elided)"
         lines.append(detail)
     return lines

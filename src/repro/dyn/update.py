@@ -10,8 +10,10 @@ patched arrays ride the existing executable.
 The patch reproduces what the format builders would pack for the mutated
 matrix whenever the geometry is preserved: ELL lanes keep their entries
 as a column-sorted prefix (re-packed after every mutation), padding stays
-``val=0 / col=0``, and seg streams keep every descriptor fixed (removals
-zero values in place, adds re-fill holes owned by the same row). On the
+``val=0 / col=0``, seg streams keep every descriptor fixed (removals
+zero values in place, adds re-fill holes owned by the same row), and dia
+steps keep their offsets (an entry on a stored diagonal is written into
+its slot; one on any other diagonal does not fit). On the
 jax backend with an ELL-family plan this makes in-capacity updates
 bit-exact against a fresh ``repro.compile`` of the mutated matrix.
 
@@ -163,6 +165,46 @@ class _SegStep:
         return int(hole[0]) if hole.size else None
 
 
+class _DiaStep:
+    """Working state for one dia spec step: slot (d, r) holds the entry
+    (r, r + offsets[d]); a zero there, on the matrix, is a free slot."""
+
+    def __init__(self, step: dict, fmt: dict):
+        self.step = step
+        self.key = step["key"]
+        vals = np.asarray(fmt[f"{self.key}_vals"])
+        self.shape = tuple(vals.shape)
+        self.vals_dtype = vals.dtype
+        self.vals = vals.astype(np.float32).reshape(self.shape[0], -1)
+        self.offsets = np.asarray(step["offsets"], np.int64)
+        self.mutable = True      # adds land on the stored diagonals
+        self.dirty_vals = False
+        self.dirty_cols = False
+
+    def slots(self, rows, cols) -> np.ndarray:
+        """Diagonal of each (row, col), -1 where it is not stored."""
+        off = np.asarray(cols, np.int64) - np.asarray(rows, np.int64)
+        if not self.offsets.size:
+            return np.full(off.shape, -1, np.int64)
+        d = np.minimum(np.searchsorted(self.offsets, off),
+                       self.offsets.size - 1)
+        return np.where(self.offsets[d] == off, d, -1)
+
+    def slot(self, row: int, col: int):
+        d = int(self.slots([row], [col])[0])
+        return None if d < 0 else (d, row)
+
+    def find(self, row: int, col: int):
+        at = self.slot(row, col)
+        return at if at is not None and self.vals[at] != 0.0 else None
+
+
+def _index(found) -> tuple:
+    """A step's ``find`` result as an index into its vals: ELL's (t, r, w)
+    and dia's (d, r) already are; seg's flat position is wrapped."""
+    return found if isinstance(found, tuple) else (found,)
+
+
 class PlanPatcher:
     """Applies :class:`PatternDelta` streams to one plan, incrementally.
 
@@ -188,6 +230,8 @@ class PlanPatcher:
                 self.steps.append(_EllStep(step, plan.fmt))
             elif step["kind"] == "seg":
                 self.steps.append(_SegStep(step, plan.fmt))
+            elif step["kind"] == "dia":
+                self.steps.append(_DiaStep(step, plan.fmt))
             else:
                 raise TypeError(f"unknown spec step kind {step['kind']!r}: "
                                 "cannot patch custom layouts in place")
@@ -213,17 +257,12 @@ class PlanPatcher:
         st, found = self._locate(row, col)
         if st is None:
             return   # already absent from storage (e.g. bf16 underflow)
-        if isinstance(st, _EllStep):
-            t, r, w = found
-            undo.append((st.vals, (t, r, w), float(st.vals[t, r, w])))
-            st.vals[t, r, w] = 0.0
-            st.dirty_vals = True
-            if st.mutable:
-                st.repack(t, r, undo)
-        else:
-            undo.append((st.vals, (found,), float(st.vals[found])))
-            st.vals[found] = 0.0
-            st.dirty_vals = True
+        at = _index(found)
+        undo.append((st.vals, at, float(st.vals[at])))
+        st.vals[at] = 0.0
+        st.dirty_vals = True
+        if isinstance(st, _EllStep) and st.mutable:
+            st.repack(found[0], found[1], undo)
 
     def _revalue(self, row: int, col: int, v: float, undo: list,
                  reasons: list) -> None:
@@ -235,13 +274,9 @@ class PlanPatcher:
         if st is None:
             self._add(row, col, v, undo, reasons)
             return
-        if isinstance(st, _EllStep):
-            t, r, w = found
-            undo.append((st.vals, (t, r, w), float(st.vals[t, r, w])))
-            st.vals[t, r, w] = q
-        else:
-            undo.append((st.vals, (found,), float(st.vals[found])))
-            st.vals[found] = q
+        at = _index(found)
+        undo.append((st.vals, at, float(st.vals[at])))
+        st.vals[at] = q
         st.dirty_vals = True
 
     def _add(self, row: int, col: int, v: float, undo: list,
@@ -288,14 +323,26 @@ class PlanPatcher:
                 s.dirty_vals = True
                 s.dirty_cols = True
                 return
+        # 3) the free slot of a stored diagonal
+        for s in self.steps:
+            if isinstance(s, _DiaStep):
+                at = s.slot(row, col)
+                if at is None:
+                    continue
+                undo.append((s.vals, at, float(s.vals[at])))
+                s.vals[at] = q
+                s.dirty_vals = True
+                return
         reasons.append(self._why_no_capacity(row, col))
 
     def _revalue_bulk(self, rows, cols, vals, undo: list,
                       reasons: list) -> None:
-        """Vectorized revalue of existing ELL entries; everything else
-        (zero-quantized, missing, seg-resident) falls back to the per-op
-        path. Training-style churn is revalue-dominated, so this is what
-        keeps ``apply`` O(delta) with array-op (not per-entry) constants.
+        """Vectorized revalue of existing ELL entries and of entries on
+        stored dia diagonals (present or not: either way the value goes
+        into its slot); everything else (zero-quantized, missing,
+        seg-resident) falls back to the per-op path. Training-style churn
+        is revalue-dominated, so this is what keeps ``apply`` O(delta)
+        with array-op (not per-entry) constants.
         """
         rows = np.asarray(rows, np.int64)
         cols = np.asarray(cols, np.int64)
@@ -306,6 +353,20 @@ class PlanPatcher:
             q = vals
         pending = q != 0.0           # zero-quantized -> per-op remove path
         for st in self.steps:
+            if isinstance(st, _DiaStep) and pending.any():
+                idx = np.nonzero(pending)[0]
+                # out-of-range entries take the per-op path, which rejects
+                ok = ((rows[idx] >= 0) & (rows[idx] < self.spec["n_rows"])
+                      & (cols[idx] >= 0) & (cols[idx] < self.spec["n_cols"]))
+                idx = idx[ok]
+                d = st.slots(rows[idx], cols[idx])
+                idx, d = idx[d >= 0], d[d >= 0]
+                r = rows[idx]
+                undo.append((st.vals, (d, r), st.vals[d, r].copy()))
+                st.vals[d, r] = q[idx]
+                st.dirty_vals = True
+                pending[idx] = False
+                continue
             if not isinstance(st, _EllStep) or not st.lane_t.size \
                     or not pending.any():
                 continue
@@ -369,6 +430,8 @@ class PlanPatcher:
                 tag = (f"{s.key}:no free position in row segment"
                        if s.mutable else f"{s.key}:cols frozen(model-elided)")
                 owners.append(tag)
+            elif isinstance(s, _DiaStep):
+                owners.append(f"{s.key}:diagonal {col - row} not stored")
         why = "; ".join(owners) if owners else "row unmapped in every step"
         return f"add ({row},{col}): {why}"
 

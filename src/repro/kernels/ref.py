@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro import telemetry
 
 __all__ = ["gather", "ell_spmv_ref", "seg_spmv_ref", "ell_spmm_ref",
-           "seg_spmm_ref"]
+           "seg_spmm_ref", "dia_spmv_ref"]
 
 
 def _f32(a):
@@ -92,3 +92,23 @@ def seg_spmm_ref(vals, cols, local_row, seg_end, x, seg_rows: int,
     g_prev = jnp.concatenate([jnp.zeros((T, 1, B), g.dtype), g[:, :-1]],
                              axis=1)
     return g - g_prev
+
+
+# --------------------------------- DIA ---------------------------------------
+
+def dia_spmv_ref(vals, xp, offsets, pad_left: int, n_rows: int) -> jax.Array:
+    """Diagonal SpMV as XLA static slices of the zero-padded x.
+
+    vals (D, NB, 128), diagonal d's value of row r at vals[d].ravel()[r];
+    xp the zero-padded x, (L,) or (L, B), with x[c] at xp[pad_left + c]
+    -> fp32 y (n_rows,) or (n_rows, B): the sum over d of vals[d] times
+    the window xp[pad_left + offsets[d]:][:n_rows], in the kernel's order.
+    """
+    v = _f32(vals).reshape(len(offsets), -1)[:, :n_rows]
+    if xp.ndim > 1:
+        v = v[..., None]
+    y = jnp.zeros((n_rows,) + xp.shape[1:], jnp.float32)
+    for d, off in enumerate(offsets):
+        start = pad_left + off
+        y = y + v[d] * _f32(jax.lax.slice_in_dim(xp, start, start + n_rows))
+    return y

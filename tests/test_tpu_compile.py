@@ -7,7 +7,8 @@ M) geometry ``plan_format`` emits for its two matrices — the 27-point
 stencil row (R=8, W=27), a power-law matrix's widest row (W past one
 128-lane chunk and not a multiple of it), and its 2048- and 512-nnz seg
 tiles. The dist path's canonical (8, 8) ELL chunks and (16, 8) seg chunks
-are compiled too.
+are compiled too, and the diagonal kernel at HPCG's 27 offsets (plane
+stride 10,816) and at a 7-offset band.
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process at a time may load the TPU library.
@@ -126,3 +127,35 @@ def test_bf16_storage_lowers(one_chip):
                                mode="seg_scan", interpret=False),
              ((T, S, L), jnp.bfloat16),
              *_seg_args(T, S, L, M, (N_COLS,), True)[1:])
+
+
+DIA_OFFSETS = {
+    "hpcg27": tuple(sorted(dz * 10_816 + dy * 104 + dx for dz in (-1, 0, 1)
+                           for dy in (-1, 0, 1) for dx in (-1, 0, 1))),
+    "band7": tuple(range(-3, 4)),
+}
+
+
+@pytest.mark.parametrize("n_rows", [131_072, 1_124_864 // 8 + 77])
+@pytest.mark.parametrize("offsets", sorted(DIA_OFFSETS))
+def test_dia_entry_point_lowers(one_chip, offsets, n_rows):
+    """The diagonal kernel with x whole-resident in VMEM, at a row count
+    that fills whole tiles and one that ends in a partial tile."""
+    _compile_dia(one_chip, DIA_OFFSETS[offsets], n_rows, jnp.float32)
+
+
+@pytest.mark.parametrize("n_rows", [300, 1_500, 5_000, 140_685])
+def test_dia_bf16_storage_lowers(one_chip, n_rows):
+    """bf16-stored vals, also in tiles shorter than one chunk and with a
+    short last chunk (static starts)."""
+    _compile_dia(one_chip, DIA_OFFSETS["band7"], n_rows, jnp.bfloat16)
+
+
+def _compile_dia(sharding, offs, n_rows, dtype):
+    from repro.kernels.dia_spmv import geometry
+    pad_left, x_rows = geometry(n_rows, offs)
+    nb = -(-n_rows // 128)
+    op = functools.partial(ops.dia_spmv, offsets=offs, pad_left=pad_left,
+                           n_rows=n_rows, interpret=False)
+    _compile(sharding, op, ((len(offs), nb, 128), dtype),
+             ((x_rows, 128), jnp.float32))
