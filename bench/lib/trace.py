@@ -14,6 +14,10 @@ device numbers.
   Mosaic kernel is an op whose HLO is a custom call to
   ``tpu_custom_call``; its time is ``kernel_s`` and every other op's
   (gathers, copies, the combine, the chain's rescale) is ``other_s``.
+* An op that encloses others on its line, such as a ``while`` loop or its
+  body around the ops it runs, is not an op of its own: only leaf ops add
+  to ``kernel_s``, ``other_s`` and the breakdown. Every op, enclosing or
+  not, counts towards ``busy_s``.
 * Idle gaps are the stretches of the window in which no op ran on a
   device, each labelled by the innermost ``bench.*`` host span open at the
   gap's middle (``host`` where none is).
@@ -73,6 +77,24 @@ def union_length(intervals) -> tuple[float, list]:
     return sum(e - s for s, e in merged), merged
 
 
+def enclosing(intervals) -> list[bool]:
+    """For each (start, end) interval of one line, whether it encloses
+    another of positive length, one that starts no earlier and ends no
+    later; of two equal intervals the first encloses the second."""
+    order = sorted(range(len(intervals)),
+                   key=lambda i: (intervals[i][0], -intervals[i][1]))
+    outer = [False] * len(intervals)
+    stack = []                          # the intervals open at the start
+    for i in order:
+        s, e = intervals[i]
+        while stack and intervals[stack[-1]][1] <= s:
+            stack.pop()
+        if stack and s < e <= intervals[stack[-1]][1]:
+            outer[stack[-1]] = True
+        stack.append(i)
+    return outer
+
+
 def _host_spans(planes):
     """(window, spans): the ``bench.window`` interval and every other
     ``bench.*`` host span, in ns."""
@@ -116,9 +138,14 @@ def reduce_planes(planes) -> Summary:
         for line in plane.lines:
             if line.name != OPS_LINE:
                 continue
-            for ev in line.events:
-                s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-                intervals.append((s, e))
+            events = list(line.events)
+            line_iv = [(ev.start_ns, ev.start_ns + ev.duration_ns)
+                       for ev in events]
+            intervals += line_iv
+            for ev, (s, e), outer in zip(events, line_iv,
+                                         enclosing(line_iv)):
+                if outer:
+                    continue
                 by_name[ev.name] = by_name.get(ev.name, 0.0) + (e - s)
                 if is_mosaic(ev.name):
                     kernel_ns += e - s

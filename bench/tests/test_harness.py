@@ -1,13 +1,14 @@
 """The harness on the CPU at a tiny size: the no-chip guard, the peaks
-table, resolution by name from files added beside the benchmark, and
-``correct`` coming out false for a broken timed path and for the
-lower-precision control."""
+table, resolution by name from files added beside the benchmark, calls
+dispatched one by one or K to a program, and ``correct`` coming out false
+for a broken timed path and for the lower-precision control."""
 import json
 import os
 import subprocess
 import sys
 
 import pytest
+from jax.tree_util import Partial
 
 from bench import control
 from bench.lib import device
@@ -15,6 +16,9 @@ from bench.lib.registry import BENCH_DIR, ROOT, BenchError, Registry
 from bench.run import main, run_cell
 
 TINY_CELL = "tiny-hpcg-chain"
+# the tiny chain cell by calls a program: K = 1 is the cell's file without
+# ``calls_per_dispatch``, K = 4 a cell that sets it
+TINY_K = {1: TINY_CELL, 4: f"{TINY_CELL}-k4"}
 
 
 def tiny_registry(tmp) -> Registry:
@@ -28,13 +32,16 @@ def tiny_registry(tmp) -> Registry:
         "params": {"nx": 8, "ny": 8, "nz": 8, "seed": 1}}))
     params = json.loads((BENCH_DIR / "workloads" / "hpcg-cg-b1.json")
                         .read_text())
+    params.pop("calls_per_dispatch", None)
     (tmp / "workloads" / f"{TINY_CELL}.json").write_text(json.dumps(params))
     (tmp / "workloads" / f"{TINY_CELL}-b2.json").write_text(
         json.dumps(dict(params, batch=2)))
+    (tmp / "workloads" / f"{TINY_K[4]}.json").write_text(
+        json.dumps(dict(params, calls_per_dispatch=4)))
     (tmp / "metrics" / "tiny_calls.py").write_text(
         "def read(run):\n    return float(run.calls)\n")
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for cell in (TINY_CELL, f"{TINY_CELL}-b2"):
+    for cell in (TINY_CELL, f"{TINY_CELL}-b2", TINY_K[4]):
         spec["workloads"].append({"name": cell, "config": "tiny-hpcg",
                                   "traffic": "chain", "chips": 1,
                                   "why": "test"})
@@ -43,7 +50,7 @@ def tiny_registry(tmp) -> Registry:
                                "source": "host_clock",
                                "workloads": [TINY_CELL]})
     for m in spec["per_layer"]:
-        m["workloads"].append(TINY_CELL)
+        m["workloads"] += [TINY_CELL, TINY_K[4]]
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
     return Registry.from_file(tmp / "BENCHMARK.json", dirs=(tmp, BENCH_DIR))
 
@@ -52,6 +59,11 @@ def tiny_registry(tmp) -> Registry:
 def tiny(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("bench")
     return tiny_registry(tmp), tmp / "cache"
+
+
+@pytest.fixture(params=sorted(TINY_K), ids=lambda k: f"K{k}")
+def k(request):
+    return request.param
 
 
 def run_tiny(tiny, seed=3, trace=False, plan_hook=None, cell=TINY_CELL):
@@ -143,12 +155,46 @@ def test_hbm_roofline_reads_device_time(tiny):
     assert reader.read(run) is None
 
 
-def test_traced_run_reports_per_layer(tiny):
-    res = run_tiny(tiny, trace=True)
+@pytest.mark.parametrize("value", [0, -1, 2.5, True, "4"])
+def test_calls_per_dispatch_refuses_bad_values(tiny, value):
+    chain = tiny[0].module("traffic", "chain")
+    with pytest.raises(BenchError, match="calls_per_dispatch"):
+        chain.calls_per_dispatch({"calls_per_dispatch": value})
+
+
+def test_calls_per_dispatch(tiny, monkeypatch, k):
+    """K absent runs the call-by-call loop; K > 1 runs K chained calls a
+    program, and ``attempted`` counts programs x K."""
+    registry, _ = tiny
+    chain = registry.module("traffic", "chain")
+    seen = {}
+
+    def spy(name):
+        fn = getattr(chain, name)
+
+        def wrapped(*a, **kw):
+            seen[name] = out = fn(*a, **kw)
+            return out
+        monkeypatch.setattr(chain, name, wrapped)
+    for name in ("stepwise", "chained", "window"):
+        spy(name)
+    params = registry.data("workloads", TINY_K[k])
+    assert ("calls_per_dispatch" in params) == (k > 1)
+    res = run_tiny(tiny, cell=TINY_K[k])
+    assert res["correct"] is True, res
+    programs = seen.pop("window")[0]
+    assert programs > 0 and res["attempted"] == programs * k
+    assert set(seen) == {"stepwise" if k == 1 else "chained"}
+    assert res["checks"]["rel_err"]["value"] < 1e-5
+
+
+def test_traced_run_reports_per_layer(tiny, k):
+    res = run_tiny(tiny, trace=True, cell=TINY_K[k])
     assert res["correct"] is True
     # no TPU plane on the CPU: the trace readers return nothing, the
-    # plan's own count still reads
-    assert set(res["metrics"]) == {"stored_bytes_ratio"}
+    # plan's own count and the program's spans and counters still read
+    assert set(res["metrics"]) == {"stored_bytes_ratio", "plan_load_s",
+                                   "jit_load_s", "jit_compiles"}
     assert res["metrics"]["stored_bytes_ratio"]["value"] > 1
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
 
@@ -165,29 +211,44 @@ def test_main_prints_checks_last(tiny, capsys):
     assert err.strip().splitlines()[-1].startswith("check rel_err ")
 
 
+# Faults put in the plan's place. Each is a pytree (``Partial``), as the
+# plan is, so that a program of K chained calls takes it as an argument.
+def _identity(x):
+    return x
+
+
 def _unchanged(plan, sm):
-    return lambda x: x
+    return Partial(_identity)
+
+
+def _half_rows_call(plan, x):
+    return plan(x).at[plan.n_rows // 2:].set(0.0)
 
 
 def _half_rows(plan, sm):
-    return lambda x: plan(x).at[plan.n_rows // 2:].set(0.0)
+    return Partial(_half_rows_call, plan)
+
+
+def _altered_call(plan, x):
+    import jax.numpy as jnp
+    y = plan(x)
+    return y.at[7].add(1e-3 * jnp.max(jnp.abs(y)))
 
 
 def _one_answer_altered(plan, sm):
-    import jax.numpy as jnp
-    return lambda x: (lambda y: y.at[7].add(1e-3 * jnp.max(jnp.abs(y))))(
-        plan(x))
+    return Partial(_altered_call, plan)
 
 
 @pytest.mark.parametrize("fault", [_unchanged, _half_rows,
                                    _one_answer_altered])
-def test_broken_timed_path_is_not_correct(tiny, fault):
-    res = run_tiny(tiny, plan_hook=fault)
+def test_broken_timed_path_is_not_correct(tiny, fault, k):
+    res = run_tiny(tiny, plan_hook=fault, cell=TINY_K[k])
     assert res["correct"] is False
     assert res["failed"] > 0
 
 
-def test_lower_precision_control_is_not_correct(tiny):
-    res = run_tiny(tiny, plan_hook=control.lower_precision_hook())
+def test_lower_precision_control_is_not_correct(tiny, k):
+    res = run_tiny(tiny, plan_hook=control.lower_precision_hook(),
+                   cell=TINY_K[k])
     assert res["correct"] is False
     assert res["checks"]["rel_err"]["value"] > 1e-4

@@ -54,6 +54,26 @@ def test_split_busy_and_gaps():
     assert len(b["device_ops"]) == 2
 
 
+def test_enclosing():
+    iv = [(0, 100), (0, 50), (1, 2), (50, 100), (60, 70), (100, 110),
+          (110, 110), (120, 130), (120, 130)]
+    assert trace.enclosing(iv) == [True, True, False, True, False, False,
+                                   False, True, False]
+
+
+def test_enclosing_ops_add_to_busy_only():
+    """A loop op and its body around the ops they run: the leaves are the
+    kernel and XLA time and the breakdown; the loop still makes busy."""
+    loop = "%while.3 = (f32[512], f32[512]) while((f32[512], f32[512]) %t)"
+    dev = [ev(loop, 0, 100), ev("%body", 5, 90), ev(GATHER, 10, 20),
+           ev(KERNEL, 30, 40), ev(GATHER, 75, 15)]
+    s = trace.reduce_planes(planes(dev, [ev("bench.window", 0, 200)]))
+    assert s.busy_s == pytest.approx(100e-9)
+    assert s.kernel_s == pytest.approx(40e-9)
+    assert s.other_s == pytest.approx(35e-9)
+    assert {n for n, _ in s.ops} == {GATHER, KERNEL}
+
+
 def test_no_window_is_an_error():
     with pytest.raises(RuntimeError, match="bench.window"):
         trace.reduce_planes(planes([ev(KERNEL, 0, 1)], []))
@@ -82,4 +102,42 @@ def test_recorded_chip_trace():
     assert any(trace.is_mosaic(n) for n, _ in s.ops)
     assert {g[0] for g in s.gaps} <= {"bench.dispatch", "bench.rescale",
                                       "bench.wait", "bench.block", "host"}
+    assert expected["result"]["device"]["kind"] == "TPU v5 lite"
+
+
+def test_recorded_loop_trace():
+    """The tiny cell with K chained calls a program, traced on a TPU v5e:
+    each program's ``while`` op encloses its calls on the XLA Ops line and
+    counts towards busy only; the per-call sums are the leaves'."""
+    from jax.profiler import ProfileData
+    expected = json.loads((DATA / "tiny_loop.expected.json").read_text())
+    path = str(DATA / "tiny_loop.xplane.pb")
+    s = trace.reduce(path)
+    assert s.window_s == expected["window_s"]
+    assert s.busy_s == expected["busy_s"]
+    assert s.kernel_s == expected["kernel_s"] > 0
+    assert s.other_s == expected["other_s"] > 0
+    assert s.breakdown() == expected["breakdown"]
+
+    ops = [ev for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/device:TPU:")
+           for line in plane.lines if line.name == trace.OPS_LINE
+           for ev in line.events]
+    iv = [(ev.start_ns, ev.start_ns + ev.duration_ns) for ev in ops]
+    outer = trace.enclosing(iv)
+    loops = [b - a for ev, (a, b), o in zip(ops, iv, outer) if o]
+    leaves = [(ev.name, b - a) for ev, (a, b), o in zip(ops, iv, outer)
+              if not o]
+    calls = expected["calls"]
+    assert expected["calls_per_dispatch"] > 1
+    assert len(loops) == calls // expected["calls_per_dispatch"]
+    assert all(ev.name.startswith("%while") for ev, o in zip(ops, outer) if o)
+    kernels = [d for name, d in leaves if trace.is_mosaic(name)]
+    assert len(kernels) == calls            # one diagonal kernel a call
+    assert s.kernel_s / calls == pytest.approx(sum(kernels) * 1e-9 / calls)
+    assert s.kernel_s + s.other_s == pytest.approx(
+        sum(d for _, d in leaves) * 1e-9)
+    assert s.kernel_s + s.other_s <= s.busy_s <= s.window_s
+    # counted as ops of their own, the loops would pass the busy time
+    assert s.kernel_s + s.other_s + sum(loops) * 1e-9 > s.busy_s
     assert expected["result"]["device"]["kind"] == "TPU v5 lite"
